@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.core.qsdp import MeshSpec, QSDPConfig
 from repro.data import SyntheticLM, make_batch
+from repro.launch.mesh import make_mesh
 from repro.models.config import ModelConfig
 from repro.models.transformer import Model
 from repro.optim import AdamWConfig, cosine_schedule, make_adamw
@@ -40,7 +41,7 @@ def train_run(qsdp: QSDPConfig, steps: int = 200, batch: int = 8, seq: int = 128
               lr: float = 2e-3, seed: int = 0, tag: str = "", model_cfg=None,
               eval_last: int = 5) -> RunResult:
     cfg = model_cfg or BENCH_MODEL
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ms = MeshSpec(axes=("data", "model"), shape=(1, 1))
     model = Model(cfg, ms, qsdp)
     opt = make_adamw(AdamWConfig(lr=lr, schedule=cosine_schedule(lr, 20, steps)))

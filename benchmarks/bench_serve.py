@@ -63,7 +63,11 @@ Run:  PYTHONPATH=src python benchmarks/bench_serve.py --smoke
 """
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+    # 8 emulated devices when the backend is the CPU; appended, so a
+    # caller's own XLA_FLAGS survive, and ignored by a TPU backend.
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=8")
 # ^ MUST precede every other import: jax locks the device count on first init.
 
 import argparse

@@ -34,7 +34,11 @@ Run:  PYTHONPATH=src python benchmarks/bench_step.py --smoke --quantized-state
 """
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+    # 8 emulated devices when the backend is the CPU; appended, so a
+    # caller's own XLA_FLAGS survive, and ignored by a TPU backend.
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=8")
 # ^ MUST precede every other import: jax locks the device count on first init.
 
 import argparse
@@ -55,6 +59,7 @@ import numpy as np
 from repro.core.qsdp import MeshSpec, QSDPConfig, layer_gather_launches, step_comm_bytes
 from repro.tune.cost_model import CPU_SMOKE, plan_layer_policies
 from repro.data import SyntheticLM
+from repro.launch.mesh import make_mesh
 from repro.models.config import ModelConfig
 from repro.models.transformer import Model
 from repro.optim import AdamWConfig, make_adamw
@@ -181,7 +186,7 @@ def main(argv=None):
         dims = dict(n_layers=4, d_model=256, d_ff=512, seq=64, batch=8, micro=2)
         steps = args.steps or 10
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     ms = MeshSpec(axes=("data", "model"), shape=(4, 2))
     mcfg = ModelConfig(name="bench", arch_type="dense", n_layers=dims["n_layers"],
                        d_model=dims["d_model"], vocab_size=512, n_heads=8,

@@ -21,6 +21,7 @@ from repro.core.levels import (
 )
 from repro.core.qsdp import MeshSpec
 from repro.data import SyntheticLM, make_batch
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import Model
 from repro.optim import AdamWConfig, cosine_schedule, make_adamw
 from repro.train.step import init_train_state, make_jitted_train_step
@@ -38,7 +39,7 @@ def main(argv=None, out_dir="results/bench"):
     args = ap.parse_args(argv)
     os.makedirs(out_dir, exist_ok=True)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ms = MeshSpec(axes=("data", "model"), shape=(1, 1))
     model = Model(BENCH_MODEL, ms, qsdp_wg(8, 8))
     opt = make_adamw(AdamWConfig(lr=1e-3, schedule=cosine_schedule(1e-3, 20, args.steps)))

@@ -1,7 +1,8 @@
 """Quickstart: the QSDP public API in ~60 lines.
 
-Builds a small GPT, shards it over an emulated (2 data x 4 model) mesh,
-runs a few quantized-communication training steps, then generates tokens.
+Builds a small GPT, shards it over the devices it finds ((2 data x 4 model)
+on 8, e.g. emulated as below), runs a few quantized-communication training
+steps, then generates tokens.
 
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   PYTHONPATH=src python examples/quickstart.py
@@ -13,6 +14,7 @@ from jax.sharding import PartitionSpec as P
 from repro import configs
 from repro.core.qsdp import MeshSpec, QSDPConfig
 from repro.data import SyntheticLM, make_batch
+from repro.launch.mesh import local_mesh_shape, make_mesh
 from repro.models.decode import DecodeSpec
 from repro.models.transformer import Model
 from repro.optim import AdamWConfig, make_adamw
@@ -22,8 +24,8 @@ from repro.train.step import init_train_state, make_jitted_train_step
 
 def main():
     # 1. mesh: ("data", "model") — FSDP over data, tensor-parallel over model
-    dp, tp = (2, 4) if len(jax.devices()) >= 8 else (1, 1)
-    mesh = jax.make_mesh((dp, tp), ("data", "model"))
+    dp, tp = local_mesh_shape()
+    mesh = make_mesh((dp, tp), ("data", "model"))
     ms = MeshSpec(axes=("data", "model"), shape=(dp, tp))
 
     # 2. the paper's technique, as config: quantize everything FSDP transmits

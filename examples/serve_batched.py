@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.core.qsdp import QSDPConfig
 from repro.data import SyntheticLM
+from repro.launch.mesh import local_mesh_shape
 from repro.serve import (Request, build_serve_setup, make_prompt_batch,
                          make_scheduler)
 
@@ -150,7 +151,7 @@ def main():
     if args.data_par and args.model_par:
         dp, tp = args.data_par, args.model_par
     else:
-        dp, tp = (2, 4) if len(jax.devices()) >= 8 else (1, 1)
+        dp, tp = local_mesh_shape()
     qsdp = (QSDPConfig.baseline() if args.baseline
             else QSDPConfig(weight_bits=args.wbits, min_quant_size=1024))
     if (args.draft_bits > 0) != (args.draft_depth > 1):

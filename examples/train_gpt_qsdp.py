@@ -16,6 +16,7 @@ import jax
 from repro import configs
 from repro.core.qsdp import MeshSpec, QSDPConfig, step_comm_bytes
 from repro.data import SyntheticLM, make_batch
+from repro.launch.mesh import local_mesh_shape, make_mesh
 from repro.models.config import ModelConfig
 from repro.models.transformer import Model
 from repro.optim import AdamWConfig, cosine_schedule, make_adamw
@@ -34,8 +35,8 @@ def main():
     ap.add_argument("--ckpt", type=str, default=None)
     args = ap.parse_args()
 
-    dp, tp = (2, 4) if len(jax.devices()) >= 8 else (1, 1)
-    mesh = jax.make_mesh((dp, tp), ("data", "model"))
+    dp, tp = local_mesh_shape()
+    mesh = make_mesh((dp, tp), ("data", "model"))
     ms = MeshSpec(axes=("data", "model"), shape=(dp, tp))
 
     if args.full_width:

@@ -37,9 +37,8 @@ def finish() -> None:
 
 
 def mesh_and_spec(shape, axes=("data", "model")):
-    import jax
-
     from repro.core.qsdp import MeshSpec
+    from repro.launch.mesh import make_mesh
 
-    return (jax.make_mesh(tuple(shape), tuple(axes)),
+    return (make_mesh(tuple(shape), tuple(axes)),
             MeshSpec(axes=tuple(axes), shape=tuple(shape)))

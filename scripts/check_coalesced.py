@@ -31,11 +31,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core import collectives as coll
 from repro.core.qsdp import MeshSpec, QSDPConfig
 from repro.core.quant import QuantConfig
+from repro.launch.mesh import make_mesh
 from repro.models.config import ModelConfig
 from repro.models.transformer import Model
 from repro.roofline.hlo_analyzer import analyze_hlo
@@ -43,7 +44,7 @@ from repro.roofline.hlo_analyzer import analyze_hlo
 # ---------------------------------------------------------------------------
 # 1. collective-level bit-exactness, (8,) mesh
 # ---------------------------------------------------------------------------
-mesh8 = jax.make_mesh((8,), ("data",))
+mesh8 = make_mesh((8,), ("data",))
 N = 2048  # per-device shard elements (not a bucket multiple for bits=3 path)
 
 
@@ -136,7 +137,7 @@ for bits in (2, 4, 8):
 # ---------------------------------------------------------------------------
 # 2. hierarchical coalesced == per-tensor hierarchical, (2,2,2) mesh
 # ---------------------------------------------------------------------------
-mesh_pod = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh_pod = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfgh = QuantConfig(bits=8, bucket_size=256, mode="shift")
 
 
@@ -181,7 +182,7 @@ check("bf16meta-fewer-wire-bytes", b16 == b32 - 7 * 2 * 2 * (N // 256),
 # ---------------------------------------------------------------------------
 # 4-5. engine level: coalesce / prefetch vs per-tensor, (2,4) mesh
 # ---------------------------------------------------------------------------
-mesh24 = jax.make_mesh((2, 4), ("data", "model"))
+mesh24 = make_mesh((2, 4), ("data", "model"))
 ms = MeshSpec(axes=("data", "model"), shape=(2, 4))
 mcfg = ModelConfig(name="t", arch_type="dense", n_layers=3, d_model=128,
                    vocab_size=256, n_heads=8, n_kv_heads=4, head_dim=16, d_ff=256)

@@ -19,13 +19,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.core import collectives as coll
 from repro.core.qsdp import (MeshSpec, ParamSpec, QSDPConfig, QSDPEngine,
                              from_rest, to_rest)
 from repro.core.quant import QuantConfig
+from repro.launch.mesh import make_mesh
 from repro.models.config import ModelConfig
 from repro.models.transformer import Model
 from repro.models.decode import DecodeSpec
@@ -34,7 +34,7 @@ from repro.serve.engine import ServeEngine
 # ---------------------------------------------------------------------------
 # 1-2: quantized collectives numerics (1-axis)
 # ---------------------------------------------------------------------------
-mesh8 = jax.make_mesh((8,), ("data",))
+mesh8 = make_mesh((8,), ("data",))
 cfgq = QuantConfig(bits=8, bucket_size=256, mode="shift")
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 1024))
 
@@ -81,7 +81,7 @@ check("fp-reduce-scatter-exact", True)
 # ---------------------------------------------------------------------------
 # 3: hierarchical == flat (2x2x2 mesh: pod x data x model)
 # ---------------------------------------------------------------------------
-mesh_pod = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh_pod = make_mesh((2, 2, 2), ("pod", "data", "model"))
 
 
 @partial(shard_map, mesh=mesh_pod, in_specs=(P(("data", "pod")), P()),
@@ -121,7 +121,7 @@ check("hierarchical-rs", float(jnp.max(jnp.abs(out[:, 0] - out[:, 1]))) <= tol,
 # ---------------------------------------------------------------------------
 # 4: engine gather (fp path) reconstructs exactly
 # ---------------------------------------------------------------------------
-mesh24 = jax.make_mesh((2, 4), ("data", "model"))
+mesh24 = make_mesh((2, 4), ("data", "model"))
 ms = MeshSpec(axes=("data", "model"), shape=(2, 4))
 spec = ParamSpec((16, 8), tp_axis=1)
 full = jnp.arange(16 * 8, dtype=jnp.float32).reshape(16, 8)
@@ -162,7 +162,7 @@ moecfg = ModelConfig(name="tm", arch_type="moe", n_layers=2, d_model=64,
 qs_fp = dataclasses.replace(QSDPConfig.baseline(), compute_dtype="float32",
                             grad_wire_dtype="float32")
 ms11 = MeshSpec(axes=("data", "model"), shape=(1, 1))
-mesh11 = jax.make_mesh((1, 1), ("data", "model"))
+mesh11 = make_mesh((1, 1), ("data", "model"))
 
 def grads_of(model, mesh, params, batch, bspec):
     @partial(shard_map, mesh=mesh,

@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.core.qsdp import MeshSpec, QSDPConfig, from_rest
 from repro.core.quant import QuantizedParam
+from repro.launch.mesh import make_mesh
 from repro.models.config import ModelConfig
 from repro.models.transformer import Model
 from repro.optim import AdamWConfig, make_adamw
@@ -43,7 +44,7 @@ MCFG = ModelConfig(name="t", arch_type="dense", n_layers=2, d_model=64,
 
 
 def build(mesh_shape):
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     ms = MeshSpec(axes=("data", "model"), shape=mesh_shape)
     model = Model(MCFG, ms, QSDPConfig(min_quant_size=256))
     return mesh, ms, model

@@ -28,9 +28,10 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core.qsdp import MeshSpec, QSDPConfig
+from repro.launch.mesh import make_mesh
 from repro.models.config import ModelConfig
 from repro.models.transformer import Model
 from repro.roofline.hlo_analyzer import analyze_hlo
@@ -72,7 +73,7 @@ def single_gather_counts(model, name, qkey=40):
     pspec = model.param_pspecs()[name]
     eng = model.engine
 
-    @partial(shard_map, mesh=jax.make_mesh(model.ms.shape, model.ms.axes),
+    @partial(shard_map, mesh=make_mesh(model.ms.shape, model.ms.axes),
              in_specs=(pspec, P()), out_specs=P(), check_vma=False)
     def f(w, k):
         full = eng.gather(name, w, k[0])

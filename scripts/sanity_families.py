@@ -3,12 +3,13 @@
 import jax, jax.numpy as jnp
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.qsdp import MeshSpec, QSDPConfig
+from repro.launch.mesh import make_mesh
 from repro.models.config import ModelConfig
 from repro.models.transformer import Model
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 ms = MeshSpec(axes=("data", "model"), shape=(2, 4))
 qcfg = QSDPConfig(min_quant_size=256)
 

@@ -4,6 +4,7 @@ Run: PYTHONPATH=src XLA_FLAGS=--xla_force_host_platform_device_count=8 python sc
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.qsdp import MeshSpec, QSDPConfig
+from repro.launch.mesh import make_mesh
 from repro.models.config import ModelConfig
 from repro.models.transformer import Model
 from repro.models.decode import DecodeModel, DecodeSpec
@@ -12,7 +13,7 @@ from repro.optim import AdamWConfig, make_adamw
 from repro.train.step import init_train_state, make_jitted_train_step
 from repro.data import SyntheticLM
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 ms = MeshSpec(axes=("data", "model"), shape=(2, 4))
 qcfg = QSDPConfig(min_quant_size=256)
 

@@ -103,7 +103,7 @@ def _fwd_collectives(mcfg, ms, qcfg, n_layers: int, mesh) -> dict:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ..compat import shard_map
+    from jax import shard_map
     from ..models.transformer import Model
     from ..roofline.hlo_analyzer import analyze_hlo
 
@@ -134,11 +134,12 @@ def audit(arch: str = "gpt-125m", mesh_shape=(1, 1),
 
     from .. import configs
     from ..core.qsdp import MeshSpec, QSDPConfig
+    from ..launch.mesh import make_mesh
     from ..models.transformer import Model
     from ..tune.cost_model import predict_hlo_gather_counts
 
     ms = MeshSpec(axes=("data", "model"), shape=tuple(mesh_shape))
-    mesh = jax.make_mesh(ms.shape, ms.axes)
+    mesh = make_mesh(ms.shape, ms.axes)
     mcfg = configs.get_smoke(arch) if smoke else configs.get_config(arch)
     tag = f"{mcfg.name}@{ms.shape[0]}x{ms.shape[1]}"
 

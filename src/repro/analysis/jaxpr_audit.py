@@ -57,7 +57,7 @@ HAZARD_FLOAT_PRIMS = {"scatter-add", "scatter_add", "scatter-mul",
 
 
 def _subjaxprs(params: dict):
-    import jax.core as jcore
+    import jax.extend.core as jcore
     ClosedJaxpr = jcore.ClosedJaxpr
     Jaxpr = jcore.Jaxpr
     for v in params.values():
@@ -230,6 +230,7 @@ def trace_train_step(arch: str = "gpt-125m", quantized_state: bool = False,
 
     from .. import configs
     from ..core.qsdp import MeshSpec, QSDPConfig
+    from ..launch.mesh import make_mesh
     from ..models.transformer import Model
     from ..optim import AdamWConfig, make_adamw
     from ..train.step import (init_train_state, make_jitted_train_step,
@@ -237,7 +238,7 @@ def trace_train_step(arch: str = "gpt-125m", quantized_state: bool = False,
 
     cfg = configs.get_smoke(arch)
     ms = MeshSpec(axes=("data", "model"), shape=(1, 1))
-    mesh = jax.make_mesh(ms.shape, ms.axes)
+    mesh = make_mesh(ms.shape, ms.axes)
     model = Model(cfg, ms, QSDPConfig(min_quant_size=256, coalesce=True))
     opt = make_adamw(AdamWConfig(lr=1e-3))
     key = jax.random.PRNGKey(0)

@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size
+from jax.lax import axis_size
 from .quant import (
     QuantConfig,
     Quantized,
@@ -68,7 +68,7 @@ AxisNames = tuple[str, ...]
 def _axis_size(axes: AxisNames) -> int:
     s = 1
     for a in axes:
-        s *= axis_size(a)  # static int (see compat.axis_size)
+        s *= axis_size(a)  # static int
     return s
 
 

@@ -68,7 +68,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..compat import axis_size
+from jax.lax import axis_size
 from ..kernels.ops import RowQuantWeight
 from . import collectives as coll
 from .quant import (QuantConfig, QuantizedParam, dequantize, quantize,

@@ -133,12 +133,12 @@ def run_qsgd(
 ) -> tuple[jax.Array, jax.Array]:
     """Run the Theorem-2 iteration; returns (x_T, f-trajectory).
 
-    By default the iteration runs in float64 (`jax.experimental.enable_x64`
+    By default the iteration runs in float64 (`jax.enable_x64(True)`
     scoped to this call): the f(x_t) - f* gaps the rate tests fit span many
     orders of magnitude and bottom out at the f32 resolution of f after a
     handful of steps, which poisons any contraction-rate estimate."""
     if x64:
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             return _run_qsgd_impl(obj, x0.astype(jnp.float64), params, key,
                                   sigma, weight_q, grad_q_delta, record_every)
     return _run_qsgd_impl(obj, x0, params, key, sigma, weight_q, grad_q_delta,

@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size
+from jax.lax import axis_size
 
 MODEL_AXIS = "model"
 

@@ -30,20 +30,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _dqmm_kernel(nk: int, x_ref, c_ref, s_ref, z_ref, o_ref):
+def _dqmm_kernel(seg_tiles: int, x_ref, c_ref, s_ref, z_ref, o_ref):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
+    # The affine arrives transposed, (n_seg, BK): segments on sublanes, K on
+    # lanes, so the selected row broadcasts straight across x's K lanes.  The
+    # row is picked with a one-hot sum (exact: one nonzero term).
+    seg = jax.lax.broadcasted_iota(jnp.int32, s_ref.shape, 0)
+    pick = seg == pl.program_id(1) // seg_tiles
+    s = jnp.sum(jnp.where(pick, s_ref[...], 0.0), axis=0, keepdims=True)  # (1, BK)
+    z = jnp.sum(jnp.where(pick, z_ref[...], 0.0), axis=0, keepdims=True)  # (1, BK)
     x = x_ref[...].astype(jnp.float32)  # (BM, BK)
-    c = c_ref[...].astype(jnp.float32)  # (BK, BN)
-    s = s_ref[...].astype(jnp.float32)  # (BK, 1)
-    z = z_ref[...].astype(jnp.float32)  # (BK, 1)
-    xs = x * s[:, 0][None, :]  # scale folded into activations
-    acc = jnp.dot(xs, c, preferred_element_type=jnp.float32)
-    acc += jnp.sum(x * z[:, 0][None, :], axis=1, keepdims=True)  # rank-1 term
+    # u8 -> f32 through int32: Mosaic has no direct u8 -> f32 cast
+    c = c_ref[...].astype(jnp.int32).astype(jnp.float32)  # (BK, BN)
+    acc = jnp.dot(x * s, c, preferred_element_type=jnp.float32)  # scale folded into x
+    acc += jnp.sum(x * z, axis=1, keepdims=True)  # rank-1 term
     o_ref[...] += acc
 
 
@@ -63,8 +68,10 @@ def rowquant_matmul_pallas(
     x: (M, K) f32/bf16; codes: (K, N) u8; scale, zero: (K, n_seg) f32 with
     the affine constant over N-segments of size N / n_seg (n_seg == 1 is
     per-row affine).  Each n-tile must lie inside one segment (block_n
-    divides the segment — arranged upstream in ops.py), so the kernel body
-    always sees a (BK, 1) affine tile regardless of n_seg.
+    divides the segment — arranged upstream in ops.py).  The affine is
+    handed to the kernel as (n_seg, BK) blocks, whose last two dims obey
+    the TPU's (8, 128) block rule (full n_seg rows, BK lanes), and the
+    kernel selects its tile's segment row.
     Shapes must tile evenly (pad upstream in ops.py).
     """
     m, k = x.shape
@@ -76,18 +83,18 @@ def rowquant_matmul_pallas(
     seg_tiles = (n // bn) // n_seg  # n-tiles per affine segment
     assert seg_tiles * n_seg == n // bn, (n, bn, n_seg)
     grid = (m // bm, n // bn, k // bk)
-    kern = functools.partial(_dqmm_kernel, grid[2])
+    kern = functools.partial(_dqmm_kernel, seg_tiles)
     out = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bk, 1), lambda i, j, kk: (kk, j // seg_tiles)),
-            pl.BlockSpec((bk, 1), lambda i, j, kk: (kk, j // seg_tiles)),
+            pl.BlockSpec((n_seg, bk), lambda i, j, kk: (0, kk)),
+            pl.BlockSpec((n_seg, bk), lambda i, j, kk: (0, kk)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(x, codes, scale, zero)
+    )(x, codes, scale.T, zero.T)
     return out.astype(x.dtype)

@@ -11,7 +11,9 @@ hot path runs:
     CPU), otherwise the pure-jnp reference in ``core.quant``.  The two
     backends are bit-exact (tested), so this is purely a performance knob.
   * ``REPRO_PALLAS_INTERPRET`` — force (``1``) or forbid (``0``) Pallas
-    interpret mode.  Unset: interpret off-TPU, compiled on TPU.
+    interpret mode.  Unset: interpret off-TPU, compiled on TPU.  Forcing
+    interpret mode on a TPU is an error: it would run the kernels through
+    the interpreter on the host and hide the compiled path.
 
 ``core.quant.quantize`` / ``dequantize`` call :func:`quantize_packed` /
 :func:`dequantize_packed` here when the resolved backend is ``pallas``; the
@@ -50,9 +52,11 @@ def _interpret_env() -> bool | None:
 
 def _default_interpret() -> bool:
     env = _interpret_env()
-    if env is not None:
-        return env
-    return jax.default_backend() != "tpu"
+    on_tpu = jax.default_backend() == "tpu"
+    if env and on_tpu:
+        raise RuntimeError("REPRO_PALLAS_INTERPRET is set truthy on a TPU; "
+                           "unset it to run the compiled kernels")
+    return not on_tpu if env is None else env
 
 
 def resolve_backend(backend: str | None = None) -> str:

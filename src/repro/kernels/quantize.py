@@ -12,7 +12,14 @@ TPU-native Pallas kernels:
   * a block of ROWS_PER_TILE buckets is staged in VMEM per grid step;
   * randomness (stochastic-rounding uniforms, per-bucket random shifts)
     enters as a pre-generated array drawn from the SAME PRNG stream as the
-    jnp reference in ``core.quant``, so the two backends are bit-exact.
+    jnp reference in ``core.quant``, so the two backends are bit-exact;
+  * Mosaic (the TPU kernel compiler) casts neither f32 nor u8 to the other
+    directly, so every u8 conversion goes through int32;
+  * Mosaic has no lane-strided slice, so the k = 8/bits codes of a byte are
+    packed by an exact MXU matmul: a 0/2^(i*bits) matrix maps each group of
+    128*k codes (lanes) onto 128 packed bytes.  Codes are integers <= 255
+    and the weights powers of two, so bf16 operands with f32 accumulation
+    reproduce the integer sum bit-for-bit.
 
 Wire format (must match ``core.quant`` exactly — it is what goes on the
 wire in the quantized collectives):
@@ -32,8 +39,8 @@ Two kernel families live here:
      the simplest-possible reference kernels.
   2. ``quantize_pack_pallas`` / ``unpack_dequantize_pallas`` — **fused**
      quantize→bit-pack and bit-unpack→dequantize: sub-8-bit codes never
-     materialize as one-byte-per-code intermediates in HBM; the pack/unpack
-     shifts run on the VPU over the VMEM-resident tile.  These implement
+     materialize as one-byte-per-code intermediates in HBM; the pack
+     matmul and the unpack shifts run over the VMEM-resident tile.  These implement
      all three rounding modes of the wire quantizer ("nearest",
      "stochastic", "shift") and are the kernels ``core.quant`` dispatches
      to (see the ``backend=`` / ``REPRO_QUANT_BACKEND`` /
@@ -53,6 +60,16 @@ from jax.experimental import pallas as pl
 ROWS_PER_TILE = 8
 
 
+def _to_u8(x: jax.Array) -> jax.Array:
+    """Integer-valued f32/int32 -> u8 through int32 (Mosaic has no f32->u8)."""
+    return x.astype(jnp.int32).astype(jnp.uint8)
+
+
+def _from_u8(x: jax.Array) -> jax.Array:
+    """u8 -> f32 through int32 (Mosaic has no u8->f32)."""
+    return x.astype(jnp.int32).astype(jnp.float32)
+
+
 def _quantize_kernel(levels: int, stochastic: bool, x_ref, rand_ref, codes_ref, scale_ref, zero_ref):
     x = x_ref[...]  # (R, bucket) f32
     lo = jnp.min(x, axis=1, keepdims=True)
@@ -64,7 +81,7 @@ def _quantize_kernel(levels: int, stochastic: bool, x_ref, rand_ref, codes_ref, 
         codes = f + (rand_ref[...] < (v - f)).astype(v.dtype)
     else:
         codes = jnp.round(v)
-    codes_ref[...] = jnp.clip(codes, 0, levels).astype(jnp.uint8)
+    codes_ref[...] = _to_u8(jnp.clip(codes, 0, levels))
     scale_ref[...] = scale
     zero_ref[...] = lo
 
@@ -117,10 +134,25 @@ def _pack_k(bits: int) -> int:
     return 8 // bits if 8 % bits == 0 else 1
 
 
+def _pack_group(bucket: int, k: int) -> int:
+    """Codes per pack matmul: 128*k lanes in -> 128 packed lanes out, or the
+    whole bucket when it is not a multiple of 128*k."""
+    return 128 * k if bucket % (128 * k) == 0 else bucket
+
+
+def _pack_matrix(group: int, bits: int) -> jax.Array:
+    """(group, group/k) bf16 with 2^(i*bits) at row j*k+i, column j: a codes
+    row times this matrix is the packed byte row of ``core.quant.pack_codes``."""
+    k = _pack_k(bits)
+    e = jnp.arange(group)[:, None]
+    j = jnp.arange(group // k)[None, :]
+    return jnp.where(e // k == j, 2.0 ** ((e % k) * bits), 0.0).astype(jnp.bfloat16)
+
+
 def _quantize_pack_kernel(levels, bits, mode, rand_scale,
-                          x_ref, rand_ref, codes_ref, scale_ref, zero_ref):
+                          x_ref, rand_ref, pack_ref, codes_ref, scale_ref, zero_ref):
     """One (R, bucket) tile: bucketed min-max quantize with the selected
-    rounding mode, then bit-pack k = 8/bits codes per byte in-register.
+    rounding mode, then bit-pack k = 8/bits codes per byte.
 
     The arithmetic is kept expression-for-expression identical to the jnp
     reference path in ``core.quant.quantize`` so both backends produce the
@@ -146,17 +178,18 @@ def _quantize_pack_kernel(levels, bits, mode, rand_scale,
     else:  # nearest
         codes = jnp.round(v)
         zero = lo
-    codes = jnp.clip(codes, 0, levels).astype(jnp.uint8)
-    k = _pack_k(bits)
-    if k > 1:
-        # strided-slice pack: byte j <- sum_i codes[:, j*k + i] << (i*bits).
-        # Slices keep everything 2D / lane-major (no tiny minor reshape).
-        packed = codes[:, 0::k]
-        for i in range(1, k):
-            packed = packed | (codes[:, i::k] << jnp.uint8(i * bits))
+    codes = jnp.clip(codes, 0, levels)
+    if _pack_k(bits) > 1:
+        # byte j <- sum_i codes[j*k + i] << (i*bits), one exact matmul per
+        # lane group (see the module docstring).
+        group, out_group = pack_ref.shape
+        codes = codes.astype(jnp.bfloat16)
+        for g in range(x.shape[1] // group):
+            packed = jnp.dot(codes[:, g * group:(g + 1) * group], pack_ref[...],
+                             preferred_element_type=jnp.float32)
+            codes_ref[:, g * out_group:(g + 1) * out_group] = _to_u8(packed)
     else:
-        packed = codes
-    codes_ref[...] = packed
+        codes_ref[...] = _to_u8(codes)
     scale_ref[...] = scale
     zero_ref[...] = zero
 
@@ -190,6 +223,10 @@ def quantize_pack_pallas(
     n_packed = bucket // k
     grid = (nb // ROWS_PER_TILE,)
     rand_cols = rand.shape[1]
+    if k > 1:
+        pack = _pack_matrix(_pack_group(bucket, k), bits)
+    else:  # nothing to pack; the kernel ignores this operand
+        pack = jnp.zeros((1, 1), jnp.bfloat16)
     kern = functools.partial(_quantize_pack_kernel, levels, bits, mode, rand_scale)
     return pl.pallas_call(
         kern,
@@ -197,6 +234,7 @@ def quantize_pack_pallas(
         in_specs=[
             pl.BlockSpec((ROWS_PER_TILE, bucket), lambda i: (i, 0)),
             pl.BlockSpec((ROWS_PER_TILE, rand_cols), lambda i: (i, 0)),
+            pl.BlockSpec(pack.shape, lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((ROWS_PER_TILE, n_packed), lambda i: (i, 0)),
@@ -209,18 +247,18 @@ def quantize_pack_pallas(
             jax.ShapeDtypeStruct((nb, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(x, rand)
+    )(x, rand, pack)
 
 
 def _unpack_dequantize_kernel(bits, out_dtype, codes_ref, scale_ref, zero_ref, out_ref):
-    packed = codes_ref[...]  # (R, bucket*bits/8) u8
+    packed = codes_ref[...].astype(jnp.int32)  # (R, bucket*bits/8) u8 bytes
     k = _pack_k(bits)
     if k > 1:
-        mask = jnp.uint8((1 << bits) - 1)
+        mask = (1 << bits) - 1
         r, nbytes = packed.shape
         # element j*k + i of a bucket lives in bits [i*bits, (i+1)*bits) of
         # byte j; stack along a new minor axis then flatten re-interleaves.
-        parts = [(packed >> jnp.uint8(i * bits)) & mask for i in range(k)]
+        parts = [(packed >> (i * bits)) & mask for i in range(k)]
         codes = jnp.stack(parts, axis=-1).reshape(r, nbytes * k)
     else:
         codes = packed
@@ -260,7 +298,7 @@ def unpack_dequantize_pallas(
 
 
 def _dequantize_kernel(out_dtype, codes_ref, scale_ref, zero_ref, out_ref):
-    c = codes_ref[...].astype(jnp.float32)
+    c = _from_u8(codes_ref[...])
     out_ref[...] = (c * scale_ref[...] + zero_ref[...]).astype(out_dtype)
 
 

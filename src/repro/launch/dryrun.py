@@ -21,9 +21,9 @@ import traceback
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from .. import configs
-from ..compat import shard_map
 from ..configs.inputs import input_specs
 from ..core.qsdp import QSDPConfig
 from ..models.config import SHAPES

@@ -25,6 +25,7 @@ from ..core.qsdp import QSDPConfig
 from ..data import SyntheticLM
 from ..serve import (Request, build_serve_setup, make_prompt_batch,
                      make_scheduler)
+from .compile_cache import enable_compile_cache
 
 
 def _build_parser():
@@ -239,6 +240,7 @@ def run_batch(setup, args) -> int:
 
 def main(argv=None):
     args = parse_args(argv)
+    enable_compile_cache()
     if args.plan_obj is not None:
         try:
             args.plan_obj.validate_mesh(("data", "model"),

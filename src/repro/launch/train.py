@@ -15,8 +15,6 @@ import json
 import time
 
 import jax
-import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from .. import configs
 from ..core.qsdp import MeshSpec, QSDPConfig
@@ -29,6 +27,8 @@ from ..train.step import (
     make_jitted_train_step,
     quantize_train_state,
 )
+from .compile_cache import enable_compile_cache
+from .mesh import make_mesh
 
 
 def build_qsdp(args) -> QSDPConfig:
@@ -133,12 +133,14 @@ def parse_args(argv=None):
     return args
 
 
-def main(argv=None):
-    args = parse_args(argv)
-
+def train(args) -> dict:
+    """Run `args.steps` steps; returns the per-log-point records (loss,
+    grad norm, wall time since the first step, and seconds per step since
+    the previous log point, read after ``block_until_ready``), the compile
+    time, and the compiled step program."""
     nd = args.data_par * args.model_par
     assert len(jax.devices()) >= nd, (len(jax.devices()), nd)
-    mesh = jax.make_mesh((args.data_par, args.model_par), ("data", "model"))
+    mesh = make_mesh((args.data_par, args.model_par), ("data", "model"))
     ms = MeshSpec(axes=("data", "model"), shape=(args.data_par, args.model_par))
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get_config(args.arch)
@@ -148,7 +150,7 @@ def main(argv=None):
     sched = cosine_schedule(args.lr, args.warmup, args.steps)
     opt = make_adamw(AdamWConfig(lr=args.lr, schedule=sched,
                                  moment_bits=args.moment_bits))
-    state = init_train_state(model, opt, jax.random.PRNGKey(args.seed))
+    state = init_train_state(model, opt, jax.random.PRNGKey(args.seed), mesh)
     if args.quantized_state:
         state = quantize_train_state(
             state, model, jax.random.PRNGKey(args.seed + 2),
@@ -174,24 +176,45 @@ def main(argv=None):
     print(f"# {cfg.name} [{tag}] mesh=({args.data_par},{args.model_par}) "
           f"batch={args.batch} seq={args.seq} params~{cfg.n_params()/1e6:.1f}M "
           f"bigram-floor={data.bigram_entropy():.3f} nats")
+    step_key = lambda i: jax.random.fold_in(jax.random.PRNGKey(args.seed + 1), i)
     log = []
-    t0 = time.time()
     with mesh:
+        t0 = time.time()
+        # compile up front (the jitted call below reuses this executable) so
+        # compilation is reported as set-up, not as step time
+        compiled = step.lower(state, make_batch(data, 0, mesh, ms.fsdp_axes),
+                              step_key(0)).compile()
+        compile_s = time.time() - t0
+        print(f"# compiled step in {compile_s:.1f}s")
+        t0 = t_prev = time.time()
+        i_prev = -1
         for i in range(args.steps):
             batch = make_batch(data, i, mesh, ms.fsdp_axes)
-            state, m = step(state, batch, jax.random.fold_in(jax.random.PRNGKey(args.seed + 1), i))
+            state, m = step(state, batch, step_key(i))
             if i % args.log_every == 0 or i == args.steps - 1:
-                loss = float(m["loss"])
-                log.append(dict(step=i, loss=loss, gnorm=float(m["grad_norm"]),
-                                t=time.time() - t0))
-                print(f"step {i:5d} loss {loss:7.4f} gnorm {log[-1]['gnorm']:8.3f} "
-                      f"({log[-1]['t']:6.1f}s)")
+                jax.block_until_ready((state, m))
+                now = time.time()
+                log.append(dict(step=i, loss=float(m["loss"]),
+                                gnorm=float(m["grad_norm"]), t=now - t0,
+                                step_s=(now - t_prev) / (i - i_prev)))
+                t_prev, i_prev = now, i
+                print(f"step {i:5d} loss {log[-1]['loss']:7.4f} "
+                      f"gnorm {log[-1]['gnorm']:8.3f} ({log[-1]['t']:6.1f}s, "
+                      f"{log[-1]['step_s']:.3f} s/step)")
     if args.ckpt:
         save_checkpoint(args.ckpt, state, meta=dict(arch=cfg.name, steps=args.steps))
         print(f"checkpoint -> {args.ckpt}")
     if args.out_json:
         with open(args.out_json, "w") as f:
             json.dump(dict(arch=cfg.name, tag=tag, log=log), f, indent=1)
+    return dict(arch=cfg.name, tag=tag, log=log, compile_s=compile_s,
+                compiled=compiled, state=state)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    train(args)
     return 0
 
 

@@ -33,8 +33,12 @@ _DTYPE_BYTES = {
 }
 
 _COMP_HEAD_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s+->\s+.*\{")
+# TPU layouts carry parentheses inside braces (``u8[4,256]{1,0:T(4,128)}``),
+# so a brace group may hold anything but a closing brace, and a tuple type
+# may nest one level of parentheses.
 _INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s+=\s+((?:\([^)]*\))|(?:[\w\[\]{},:*\s]+?))\s+"
+    r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s+=\s+"
+    r"((?:\((?:[^()]|\([^()]*\))*\))|(?:(?:\{[^}]*\}|[\w\[\],:*\s])+?))\s+"
     r"([\w\-]+)\("
 )
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")

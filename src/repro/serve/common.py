@@ -20,6 +20,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import configs
 from ..core.qsdp import MeshSpec, QSDPConfig, step_comm_bytes
+from ..launch.mesh import make_mesh
 from ..models.config import ModelConfig
 from ..models.decode import DecodeSpec
 from ..models.transformer import Model
@@ -106,7 +107,7 @@ def build_serve_setup(arch, *, data_par: int = 1, model_par: int = 1,
                       draft_depth: int = 0) -> ServeSetup:
     """Build (mesh, model, params, DecodeSpec, ServeEngine) for serving.
     `arch` is a registry name (resolved smoke/full) or a ModelConfig."""
-    mesh = jax.make_mesh((data_par, model_par), ("data", "model"))
+    mesh = make_mesh((data_par, model_par), ("data", "model"))
     ms = MeshSpec(axes=("data", "model"), shape=(data_par, model_par))
     if isinstance(arch, ModelConfig):
         cfg = arch

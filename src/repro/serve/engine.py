@@ -28,8 +28,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
-from ..compat import shard_map
 from ..core.quant import (QuantConfig, QuantizedParam, qparam_decode,
                           qparam_encode, qparam_split_stack)
 from ..models.decode import ROWQUANT_MLP, DecodeModel, DecodeSpec, make_decode_spec

@@ -51,8 +51,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
-from ..compat import shard_map
 from ..core.quant import (
     QuantConfig,
     QuantizedParam,
@@ -69,9 +69,23 @@ class TrainState(NamedTuple):
     opt: OptState
 
 
-def init_train_state(model: Model, optimizer: Optimizer, key: jax.Array) -> TrainState:
-    params = model.init_params(key)
-    return TrainState(params=params, opt=optimizer.init(params))
+def init_train_state(model: Model, optimizer: Optimizer, key: jax.Array,
+                     mesh=None) -> TrainState:
+    """Fresh params + optimizer state.  With `mesh`, one jitted program
+    builds the state straight into its rest sharding (``state_pspecs``), so
+    no device ever holds more than its share and the first step sees the
+    same input shardings as every later one."""
+    def init(k):
+        params = model.init_params(k)
+        return TrainState(params=params, opt=optimizer.init(params))
+
+    if mesh is None:
+        return init(key)
+    specs = state_pspecs(
+        model, quantized_moments=getattr(optimizer, "quantized_moments", False))
+    shardings = jax.tree.map(lambda p: NamedSharding(mesh, p), specs,
+                             is_leaf=lambda x: isinstance(x, P))
+    return jax.jit(init, out_shardings=shardings)(key)
 
 
 # -- master quantization policy (shared by quantize_master / quantized_state) --

@@ -10,6 +10,7 @@ import jax
 import numpy as np
 
 from ..core.qsdp import MeshSpec, QSDPConfig, layer_gather_launches
+from ..launch.mesh import make_mesh
 from ..models.transformer import Model
 from ..optim import AdamWConfig, make_adamw
 from ..train.step import init_train_state, make_jitted_train_step
@@ -21,7 +22,7 @@ def measure_train_step(mcfg, ms: MeshSpec, qcfg: QSDPConfig, batch: dict,
     """Median per-step wall ms of `qcfg` on the given mesh/model/batch
     (compile + 1 warmup excluded), plus the analytic launch count so the
     plan records what the measurement exercised."""
-    mesh = jax.make_mesh(ms.shape, ms.axes)
+    mesh = make_mesh(ms.shape, ms.axes)
     model = Model(mcfg, ms, qcfg)
     opt = make_adamw(AdamWConfig(lr=1e-3))
     state = init_train_state(model, opt, jax.random.PRNGKey(seed))

@@ -6,6 +6,8 @@ and by the dry-run (launch/dryrun.py) which forces 512 in-process.
 import jax
 import pytest
 
+from repro.launch.mesh import make_mesh
+
 try:
     import hypothesis  # noqa: F401  (preferred: pip install -e .[test])
 except ImportError:  # hermetic environment — run properties as seeded sweeps
@@ -18,7 +20,7 @@ except ImportError:  # hermetic environment — run properties as seeded sweeps
 def mesh11():
     """Trivial (data=1, model=1) mesh — exercises the full shard_map code
     path (collectives degenerate to identity) on one device."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.fixture(scope="session")

@@ -37,6 +37,7 @@ from hypothesis import strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.core.qsdp import MeshSpec, QSDPConfig
+from repro.launch.mesh import make_mesh
 from repro.models.config import ModelConfig
 from repro.models.decode import DecodeSpec
 from repro.models.transformer import Model
@@ -45,7 +46,7 @@ from repro.serve import (ContinuousScheduler, Request, ServeEngine,
                          prefill_bucket_sizes)
 
 MS = MeshSpec(axes=("data", "model"), shape=(1, 1))
-MESH = jax.make_mesh((1, 1), ("data", "model"))
+MESH = make_mesh((1, 1), ("data", "model"))
 GATHER_KEY = jax.random.PRNGKey(7)
 RING = 32
 VOCAB = 256
@@ -342,7 +343,7 @@ def test_moe_no_drop_isolates_tokens():
     capacity path demonstrably leaks (earlier tokens' routing decides which
     later assignments are dropped), which is why the chunked-prefill and
     pooled-decode serve paths dispatch drop-free."""
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.models.moe import MoEConfig, moe_layer
 
     cfg = MoEConfig(n_experts=4, top_k=2, d_model=16, d_ff=32, tp=1,

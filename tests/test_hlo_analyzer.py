@@ -55,6 +55,27 @@ ENTRY %main (p0: u8[4,64], p1: f32[4,64]) -> f32[16,64] {
     assert coll["all-gather"] == 768 + 3072
 
 
+def test_tpu_tiled_layouts_parse():
+    # TPU-compiled text puts tiling parentheses inside the layout braces and
+    # /*index=N*/ comments inside tuple types; every instruction must still
+    # parse, or a chip step would report no collectives at all.
+    hlo = """\
+HloModule m
+
+ENTRY %main (p0: u8[1,256]) -> (u8[4,256], s32[]) {
+  %p0 = u8[1,256]{1,0:T(4,128)(4,1)S(1)} parameter(0)
+  %agu = u8[4,1,256]{2,1,0:T(4,128)(4,1)S(1)} all-gather(%p0), channel_id=1, replica_groups={{0,1,2,3}}, dimensions={0}, use_global_device_ids=true
+  %a2a = u8[4,1,256]{2,1,0:T(4,128)(4,1)S(1)} all-to-all(%agu), channel_id=2, replica_groups={{0,1,2,3}}, dimensions={0}
+  %r = u8[4,256]{1,0:T(4,128)(4,1)} reshape(%a2a)
+  %i = s32[]{:T(128)} constant(0)
+  ROOT %t = (u8[4,256]{1,0:T(4,128)(4,1)}, /*index=1*/s32[]{:T(128)}) tuple(%r, %i)
+}
+"""
+    coll = analyze_hlo(hlo)["collectives"]
+    assert coll["counts_by_dtype"] == {"all-gather:u8": 1, "all-to-all:u8": 1}
+    assert coll["all-gather"] == 1024 * 3 // 4
+
+
 def test_collective_classification_and_wire_formulas():
     hlo = SUM + """\
 

@@ -11,6 +11,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.quant import QuantConfig, dequantize, quantize
 from repro.kernels import ops, ref
+from repro.launch.mesh import make_mesh
 
 KEY = jax.random.PRNGKey(0)
 
@@ -102,13 +103,26 @@ def test_resolve_backend_env(monkeypatch):
         ops.resolve_backend()
 
 
+def test_forced_interpret_on_tpu_is_an_error(monkeypatch):
+    """On a TPU the kernels run compiled: forcing interpret mode there
+    raises instead of silently running them on the host."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    assert ops._default_interpret() is False
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    assert ops._default_interpret() is False
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    with pytest.raises(RuntimeError, match="REPRO_PALLAS_INTERPRET"):
+        ops._default_interpret()
+
+
 def test_quantized_collectives_backend_agnostic():
     """all_gather / reduce-scatter wire payloads are backend-independent
     (quantize is vmapped over per-peer chunks inside the collectives)."""
     from repro.core import collectives as coll
-    from repro.compat import shard_map
+    from jax import shard_map
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     x = jax.random.normal(KEY, (2048,))
     outs = {}
     for b in ("jnp", "pallas"):
@@ -173,12 +187,12 @@ def test_gather_rowquant_eligibility():
 
 def test_gather_rowquant_matches_dense_gather():
     """dequant(RowQuantWeight) == the dense gather's weight (same codes)."""
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.kernels.ops import RowQuantWeight
 
     model = _tiny_dense_model()
     eng = model.engine
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = model.init_params(jax.random.PRNGKey(0))
     name = "layers/w_gate"
     local = params[name][0]  # layer 0 slice
@@ -216,7 +230,7 @@ def test_serve_rowquant_decode_audio_hybrid(arch):
     cfg = configs.get_smoke(arch)
     model = Model(cfg, MeshSpec(axes=("data", "model"), shape=(1, 1)),
                   QSDPConfig(min_quant_size=256, bucket_size=128))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = model.init_params(jax.random.PRNGKey(0))
     B, S = 2, 16
     batch = {"tokens": jnp.ones((B, S), jnp.int32)}
@@ -247,7 +261,7 @@ def test_serve_rowquant_decode_matches_dense(backend, monkeypatch):
     from repro.serve.engine import ServeEngine
 
     model = _tiny_dense_model()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = model.init_params(jax.random.PRNGKey(0))
     batch = {"tokens": jnp.ones((2, 16), jnp.int32)}
     bspecs = {"tokens": P(("data",))}

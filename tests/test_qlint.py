@@ -18,6 +18,7 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from repro.analysis import key_audit, source_lint
 from repro.analysis.collective_audit import (diff_gather_counts,
@@ -25,8 +26,8 @@ from repro.analysis.collective_audit import (diff_gather_counts,
 from repro.analysis.findings import load_baseline, partition_findings
 from repro.analysis.jaxpr_audit import audit_jaxpr
 from repro.analysis.key_audit import MASTER_SALT, KeyUse, check_key_uses
-from repro.compat import shard_map
 from repro.core.quant import QuantConfig, dequantize, quantize
+from repro.launch.mesh import make_mesh
 
 ROOT = Path(__file__).resolve().parents[1]
 CFG = QuantConfig(bits=8, bucket_size=64, mode="nearest")
@@ -150,7 +151,7 @@ def test_jaxpr_audit_clean_when_values_change():
 
 
 def test_jaxpr_audit_fires_on_u8_widening_before_collective():
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = make_mesh((1,), ("x",))
 
     @partial(shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
              check_vma=False)
@@ -165,7 +166,7 @@ def test_jaxpr_audit_fires_on_u8_widening_before_collective():
 
 
 def test_jaxpr_audit_clean_when_gathering_u8():
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = make_mesh((1,), ("x",))
 
     @partial(shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
              check_vma=False)

@@ -6,8 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core.qsdp import MeshSpec, QSDPConfig
 from repro.core.quant import QuantizedParam, qparam_decode
 from repro.models.config import ModelConfig

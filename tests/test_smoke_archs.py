@@ -6,9 +6,9 @@ import jax.numpy as jnp
 import pytest
 from functools import partial
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from repro import configs
-from repro.compat import shard_map
 from repro.core.qsdp import MeshSpec, QSDPConfig
 from repro.models.transformer import Model
 

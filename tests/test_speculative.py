@@ -36,6 +36,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.qsdp import MeshSpec, QSDPConfig
 from repro.core.quant import QuantizedParam
+from repro.launch.mesh import make_mesh
 from repro.models.config import ModelConfig
 from repro.models.decode import DecodeSpec
 from repro.models.transformer import Model
@@ -43,7 +44,7 @@ from repro.serve import ContinuousScheduler, Request, ServeEngine
 from repro.serve.engine import make_draft_params, make_sample_params
 
 MS = MeshSpec(axes=("data", "model"), shape=(1, 1))
-MESH = jax.make_mesh((1, 1), ("data", "model"))
+MESH = make_mesh((1, 1), ("data", "model"))
 GATHER_KEY = jax.random.PRNGKey(7)
 RING = 32
 VOCAB = 256

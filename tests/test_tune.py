@@ -21,8 +21,8 @@ import jax
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core.qsdp import MeshSpec, QSDPConfig, layer_gather_launches
 from repro.models.config import ModelConfig
 from repro.models.transformer import Model

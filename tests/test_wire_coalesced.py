@@ -21,8 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core import collectives as coll
 from repro.core.qsdp import MeshSpec, QSDPConfig, layer_gather_launches
 from repro.core.quant import (
