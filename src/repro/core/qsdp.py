@@ -70,6 +70,7 @@ from jax.sharding import PartitionSpec as P
 
 from jax.lax import axis_size
 from ..kernels.ops import RowQuantWeight
+from ..obs import scoped
 from . import collectives as coll
 from .quant import (QuantConfig, QuantizedParam, dequantize, quantize,
                     unpack_codes, wire_unpack)
@@ -347,6 +348,7 @@ class _GatherStatic:
 # ---------------------------------------------------------------------------
 
 
+@scoped("qsdp_gather")
 def _gather_fwd_impl(flat: jax.Array, key: jax.Array, st: _GatherStatic) -> jax.Array:
     out_dt = getattr(jnp, st.gather_out_dtype) if st.gather_out_dtype else None
     if st.wcfg is None:
@@ -358,6 +360,7 @@ def _gather_fwd_impl(flat: jax.Array, key: jax.Array, st: _GatherStatic) -> jax.
                                      out_dtype=out_dt)
 
 
+@scoped("qsdp_reduce_scatter")
 def _grad_rs_impl(ct: jax.Array, key: jax.Array, st: _GatherStatic) -> jax.Array:
     # Gradient semantics (see core/tp.py docstring): the loss function returns
     # the per-device local-batch mean with no collectives on the loss path;
@@ -475,6 +478,7 @@ def _layer_keys(key: jax.Array, st: _LayerStatic) -> list:
     return [jax.random.fold_in(key, _stable_hash(n)) for n in st.names]
 
 
+@scoped("qsdp_gather")
 def _layer_encode_gather(shards, key: jax.Array, st: _LayerStatic) -> jax.Array:
     keys = _layer_keys(key, st)
     buf = coll.encode_wire([s.reshape(-1) for s in shards],
@@ -483,6 +487,7 @@ def _layer_encode_gather(shards, key: jax.Array, st: _LayerStatic) -> jax.Array:
     return coll.gather_wire(buf, st.fsdp_axes, pod_axis=pod)
 
 
+@scoped("qsdp_gather")
 def _layer_decode(wire: jax.Array, st: _LayerStatic):
     out_dt = getattr(jnp, st.gather_out_dtype) if st.gather_out_dtype else jnp.float32
     dts = [out_dt if q else jnp.float32 for q in st.quant]
@@ -490,6 +495,7 @@ def _layer_decode(wire: jax.Array, st: _LayerStatic):
         wire, st.gather_layout(), st.fsdp_size(), dts))
 
 
+@scoped("qsdp_reduce_scatter")
 def _layer_grad_rs(cts, key: jax.Array, st: _LayerStatic):
     p = st.fsdp_size()
     keys = [jax.random.fold_in(k, 0x5D) for k in _layer_keys(key, st)]
@@ -809,6 +815,7 @@ class QSDPEngine:
         q = wire_unpack(qp.wire.reshape(-1), qp.n, qp.cfg)
         return self._assemble_rowquant(self.specs[name], qp.cfg, q)
 
+    @scoped("qsdp_gather")
     def gather_wire_dequant(self, name: str, qp: QuantizedParam) -> jax.Array:
         """Dense fallback for a wire-form parameter that the rowquant matmul
         can't tile (attention projections, 3D expert stacks, odd buckets):

@@ -186,13 +186,16 @@ def run_continuous(setup, args) -> int:
     st = sched.stats()
     lat = [c.finish_step - c.submit_step for c in done.values()]
     ttft = [c.first_token_time - c.submit_time for c in done.values()]
+    n_first = max(1, st["first_tokens"])
     print(f"# {setup.cfg.name} continuous: {len(done)} requests, "
           f"{st['tokens_generated']} tokens in {dt:.2f}s "
           f"({st['tokens_generated'] / dt:.1f} tok/s incl. compile), "
           f"occupancy {st['mean_occupancy']:.2f}/{st['slots']}, "
           f"latency p50={np.percentile(lat, 50):.0f} "
           f"p95={np.percentile(lat, 95):.0f} steps, "
-          f"ttft p95={np.percentile(ttft, 95):.3f}s")
+          f"ttft p95={np.percentile(ttft, 95):.3f}s "
+          f"(mean queue wait {st['queue_wait_s'] / n_first:.3f}s, "
+          f"prefill wait {st['prefill_wait_s'] / n_first:.3f}s)")
     if args.prefill_chunk:
         print(f"# chunked prefill: chunk={args.prefill_chunk} "
               f"buckets={sched.buckets} -> {st['prefill_chunks']} chunk "

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.tp import tp_copy, tp_reduce
+from ..obs import scoped
 from .layers import apply_rope
 
 MODEL_AXIS = "model"
@@ -191,6 +192,7 @@ def flash_attention(
 # ---------------------------------------------------------------------------
 
 
+@scoped("attention")
 def self_attention(
     x: jax.Array,  # (B, S, d) replicated over model
     w: dict,  # gathered TP-local weights: wq,wk,wv,wo (+ optional bq,bk,bv)
@@ -267,6 +269,7 @@ def cross_attention(
 # ---------------------------------------------------------------------------
 
 
+@scoped("attention")
 def decode_new_kv(x: jax.Array, w: dict, cfg: AttnConfig, cos, sin):
     """Project this token's q (all padded heads, gathered) and full-head
     k1/v1 on every rank.  Returns (q_all (B,Hp,hd), k1, v1 (B,n_kv,hd)).
@@ -293,6 +296,7 @@ def decode_new_kv(x: jax.Array, w: dict, cfg: AttnConfig, cos, sin):
     return q_all, k1, v1
 
 
+@scoped("attention")
 def chunk_new_kv(x: jax.Array, w: dict, cfg: AttnConfig, cos, sin):
     """Multi-token variant of :func:`decode_new_kv` for chunked prefill.
 
@@ -318,6 +322,7 @@ def chunk_new_kv(x: jax.Array, w: dict, cfg: AttnConfig, cos, sin):
     return q_all, k1, v1
 
 
+@scoped("attention")
 def chunk_attend(
     q_all: jax.Array,  # (B, Lq, Hp, hd) — all (padded) query heads
     k_cache: jax.Array,  # (B, S_loc, n_kv, hd) — this rank's seq chunk,
@@ -489,6 +494,7 @@ def _kv_major_q(q_all: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             jnp.take(v_cache, kv_idx, axis=2))
 
 
+@scoped("attention")
 def decode_attend(
     q_all: jax.Array,  # (B, Hp, hd) — all (padded) query heads
     k_cache: jax.Array,  # (B, S_loc, n_kv, hd) — this rank's seq chunk,
@@ -564,6 +570,7 @@ def decode_attend(
     return o
 
 
+@scoped("attention")
 def decode_out_proj(o: jax.Array, w: dict, cfg: AttnConfig, dtype) -> jax.Array:
     """(B, Hp, hd) f32 attention output -> (B, d) via the TP-local slice of
     the row-parallel wo + psum."""

@@ -41,6 +41,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..core.quant import QuantizedParam
+from ..obs import scoped
 from . import attention as attn_mod
 from . import layers as L
 from . import mamba as mamba_mod
@@ -356,6 +357,7 @@ class DecodeModel:
             return L.mrope_cos_sin(pos3, cfg.head_dim, cfg.rope_theta, cfg.mrope_sections)
         return L.rope_cos_sin(pos, cfg.head_dim, cfg.rope_theta)
 
+    @scoped("kv_write")
     def _write_token_kv(self, kc_all, vc_all, layer, k1, v1, pos,
                         block_tables=None):
         """Write this token's KV into the scan-carried stacked cache
@@ -586,6 +588,7 @@ class DecodeModel:
     # Chunked prefill (one prompt chunk per slot, fused into the pool)
     # ------------------------------------------------------------------
 
+    @scoped("kv_write")
     def _write_chunk_kv(self, kc_all, vc_all, layer, k1, v1, pos, n_valid,
                         block_tables=None):
         """Write one chunk's KV into the stacked pool cache at each slot's

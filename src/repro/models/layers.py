@@ -18,6 +18,7 @@ from jax import lax
 
 from ..core.tp import tp_copy, tp_reduce
 from ..kernels.ops import RowQuantWeight, rowquant_matmul_dispatch
+from ..obs import scoped
 
 # ---------------------------------------------------------------------------
 # Norms
@@ -248,6 +249,7 @@ def qmatmul(x: jax.Array, w) -> jax.Array:
     return x @ w
 
 
+@scoped("mlp")
 def swiglu_mlp(x: jax.Array, w_gate, w_up, w_down) -> jax.Array:
     """Column-parallel gate/up, row-parallel down.  Weights may be dense
     arrays (training) or RowQuantWeights (quantized-weight decode)."""

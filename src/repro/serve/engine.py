@@ -34,6 +34,7 @@ from ..core.quant import (QuantConfig, QuantizedParam, qparam_decode,
                           qparam_encode, qparam_split_stack)
 from ..models.decode import ROWQUANT_MLP, DecodeModel, DecodeSpec, make_decode_spec
 from ..models.transformer import Model
+from ..obs import program
 from .kv_pool import PoolExhausted, decode_block, encode_block
 
 
@@ -236,7 +237,8 @@ class ServeEngine:
                 out_specs=(P(self.bax), self.cache_pspecs),
                 check_vma=False,
             )
-            self._decode = jax.jit(fn, donate_argnums=(1,))
+            self._decode = jax.jit(program("serve_decode", fn),
+                                   donate_argnums=(1,))
         return self._decode
 
     def prefill_step(self, batch_pspecs: dict):
@@ -254,7 +256,7 @@ class ServeEngine:
                 out_specs=(P(self.bax), self.cache_pspecs),
                 check_vma=False,
             )
-            self._prefill = jax.jit(fn)
+            self._prefill = jax.jit(program("serve_prefill", fn))
         return self._prefill
 
     def prefill_chunk_step(self, bucket_len: int):
@@ -286,7 +288,8 @@ class ServeEngine:
                 out_specs=(P(self.bax), self.cache_pspecs),
                 check_vma=False,
             )
-            self._chunk_steps[bucket_len] = jax.jit(fn, donate_argnums=(1,))
+            self._chunk_steps[bucket_len] = jax.jit(
+                program("serve_prefill_chunk", fn), donate_argnums=(1,))
         return self._chunk_steps[bucket_len]
 
     def verify_step(self, k: int):
@@ -316,7 +319,8 @@ class ServeEngine:
                 out_specs=(P(self.bax), self.cache_pspecs),
                 check_vma=False,
             )
-            self._verify_steps[k] = jax.jit(fn, donate_argnums=(1,))
+            self._verify_steps[k] = jax.jit(program("serve_verify", fn),
+                                            donate_argnums=(1,))
         return self._verify_steps[k]
 
     # -- convenience ------------------------------------------------------------

@@ -74,6 +74,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..models.decode import CHUNKED_PREFILL_ARCHS, DecodeSpec
 from ..models.transformer import Model
+from ..obs import span
 from .engine import (ServeEngine, make_draft_params, make_sample_params,
                      prefill_bucket_for, prefill_bucket_sizes)
 from .kv_pool import BlockPool, PoolExhausted, prefix_keys
@@ -110,16 +111,22 @@ class TokenEvent:
 
 @dataclasses.dataclass
 class CompletedRequest:
+    """A finished request: its tokens, and the decode-step count and host
+    clock (``time.perf_counter``) at each point of its life.  A request's
+    time to first token splits into its queue wait (``admit_time -
+    submit_time``, waiting for a slot) and its prefill wait
+    (``first_token_time - admit_time``, in a slot until token 0)."""
     rid: str
     tokens: np.ndarray  # (n_generated,) int32, includes the EOS if hit
     submit_step: int  # scheduler decode-step count at submit()
     admit_step: int  # ... when the request entered a slot (chunked: when
-    # assignment started; blocking: when the prompt was prefilled)
+    # assignment started; blocking: when the prompt's prefill was launched)
     finish_step: int  # ... when the last token was produced
     submit_time: float
     finish_time: float
     first_token_step: int = 0  # ... when token 0 (the prefill token) landed
     first_token_time: float = 0.0  # wall clock of token 0 (TTFT source)
+    admit_time: float = 0.0  # wall clock of admit_step
 
 
 @dataclasses.dataclass
@@ -260,7 +267,7 @@ class ContinuousScheduler:
         self.top_k = np.ones(self.B, np.int32)
         self.keys = np.zeros((self.B, 2), np.uint32)
         self._submit_meta: dict[str, tuple[int, float]] = {}
-        self._admit_step: dict[str, int] = {}
+        self._admit_meta: dict[str, tuple[int, float]] = {}
         self._first_token: dict[str, tuple[int, float]] = {}
         self._out: dict[str, list[int]] = {}
         self.finished: dict[str, CompletedRequest] = {}
@@ -272,6 +279,12 @@ class ContinuousScheduler:
         self._max_pf_tokens = 0  # longest single prefill launch (seq tokens)
         self.occupancy_sum = 0
         self.tokens_generated = 0
+        # the two waits that make up a time to first token, summed over
+        # requests (seconds on the host clock): submit -> slot, stamped at
+        # admission; slot -> token 0, stamped when token 0 is emitted
+        self.queue_wait_s = 0.0
+        self.prefill_wait_s = 0.0
+        self.first_tokens = 0
         # speculative-decoding stats: a "lane step" is one lane's
         # participation in one pooled launch; accepted_per_launch is
         # committed tokens per verify lane step (non-speculative decode is
@@ -452,6 +465,12 @@ class ContinuousScheduler:
         # only ever target allocated blocks
         return jnp.asarray(self.block_tables)
 
+    def _admit(self, req: Request) -> None:
+        """Stamp `req`'s entry into a slot, and add its queue wait."""
+        now = time.perf_counter()
+        self._admit_meta[req.rid] = (self.step_count, now)
+        self.queue_wait_s += now - self._submit_meta[req.rid][1]
+
     def _emit(self, events: list, slot_i: int, token: int) -> None:
         """Record one generated token for the slot's request; retire the
         slot when the request is done."""
@@ -461,23 +480,28 @@ class ContinuousScheduler:
         st.n_out += 1
         self.tokens_generated += 1
         if st.n_out == 1:
-            self._first_token[req.rid] = (self.step_count, time.perf_counter())
+            now = time.perf_counter()
+            self._first_token[req.rid] = (self.step_count, now)
+            self.prefill_wait_s += now - self._admit_meta[req.rid][1]
+            self.first_tokens += 1
         done = (st.n_out >= req.max_new_tokens
                 or (req.eos_id is not None and token == req.eos_id))
         events.append(TokenEvent(req.rid, token, st.n_out - 1, done))
         if done:
             submit_step, submit_time = self._submit_meta.pop(req.rid)
             ft_step, ft_time = self._first_token.pop(req.rid)
+            admit_step, admit_time = self._admit_meta.pop(req.rid)
             self.finished[req.rid] = CompletedRequest(
                 rid=req.rid,
                 tokens=np.asarray(self._out.pop(req.rid), np.int32),
                 submit_step=submit_step,
-                admit_step=self._admit_step.pop(req.rid),
+                admit_step=admit_step,
                 finish_step=self.step_count,
                 submit_time=submit_time,
                 finish_time=time.perf_counter(),
                 first_token_step=ft_step,
                 first_token_time=ft_time,
+                admit_time=admit_time,
             )
             if self.pool is not None:
                 self._release_lane_blocks(slot_i)
@@ -519,7 +543,7 @@ class ContinuousScheduler:
                 self.cache = _splice_slot(self.cache, cache1,
                                           jnp.asarray(slot_i, jnp.int32))
                 self.slots[slot_i] = _Slot(req=req, n_out=0)
-                self._admit_step[req.rid] = self.step_count
+                self._admit(req)
                 # slot decode state: the prefill token is fed at position s
                 self._arm_lane(slot_i, req, s)
                 admitted.append((slot_i, nxt1))
@@ -527,9 +551,11 @@ class ContinuousScheduler:
                 return
             # ONE host sync for the whole pass (the prefills above were all
             # dispatched without a device round-trip between them)
-            toks = jax.device_get([t for _, t in admitted])
-            for (slot_i, _), t in zip(admitted, toks):
-                self._emit(events, slot_i, int(np.asarray(t)[0]))
+            with span("sched.prefill_sync"):
+                toks = jax.device_get([t for _, t in admitted])
+            with span("sched.emit"):
+                for (slot_i, _), t in zip(admitted, toks):
+                    self._emit(events, slot_i, int(np.asarray(t)[0]))
 
     # -- chunked admission (prefill_chunk > 0) -------------------------------
 
@@ -555,7 +581,7 @@ class ContinuousScheduler:
             self.queue.popleft()
             st = _Slot(req=req, n_out=0, prefilling=True)
             self.slots[slot_i] = st
-            self._admit_step[req.rid] = self.step_count
+            self._admit(req)
             if self.pool is not None:
                 st.pkeys = prefix_keys(req.prompt, self.spec.kv_block_size)
                 st.reserve = need
@@ -584,73 +610,84 @@ class ContinuousScheduler:
                      if s is not None and s.prefilling]
             if not lanes:
                 return
-            clen = {i: min(self.prefill_chunk,
-                           len(self.slots[i].req.prompt) - self.slots[i].pf_off)
-                    for i in lanes}
-            bucket = prefill_bucket_for(max(clen.values()), self.buckets)
-            tokens = np.zeros((self.B, bucket), np.int32)
-            offset = np.zeros(self.B, np.int32)
-            n_valid = np.zeros(self.B, np.int32)
-            temp = np.zeros(self.B, np.float32)
-            top_k = np.ones(self.B, np.int32)
-            keys = np.zeros((self.B, 2), np.uint32)
-            for i in lanes:
-                st = self.slots[i]
-                tokens[i, :clen[i]] = st.req.prompt[st.pf_off:st.pf_off + clen[i]]
-                offset[i] = st.pf_off
-                n_valid[i] = clen[i]
-                temp[i] = st.req.temperature
-                top_k[i] = st.req.top_k
-                keys[i] = np.asarray(jax.random.PRNGKey(st.req.seed), np.uint32)
-            bt = ()
-            if self.pool is not None:
-                bs = self.spec.kv_block_size
-                for i in lanes:
-                    st = self.slots[i]
-                    for j in range(st.pf_off // bs,
-                                   -(-(st.pf_off + clen[i]) // bs)):
-                        if self.block_tables[i, j] < 0:
-                            self.block_tables[i, j] = self._lane_alloc(st)
-                bt = (self._bt_device(),)
-            extra = ()
-            if self.spec.sampling:
-                extra = ({"temp": jnp.asarray(temp),
-                          "top_k": jnp.asarray(top_k),
-                          "key": jnp.asarray(keys)},)
-            nxt, self.cache = self.engine.prefill_chunk_step(bucket)(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(offset), jnp.asarray(n_valid), *bt,
-                self.gather_key, *extra)
-            self.prefill_chunk_count += 1
-            self._pf_shapes.add(bucket)
-            self._max_pf_tokens = max(self._max_pf_tokens, bucket)
-            finishing = []
-            for i in lanes:
-                st = self.slots[i]
-                st.pf_off += clen[i]
-                if self.pool is not None and self.prefix_share:
-                    # publish every prompt block this chunk completed (all
-                    # chunk offsets are multiples of the chunk size from 0,
-                    # so the block bytes are the canonical decomposition's)
-                    full = min(st.pf_off // self.spec.kv_block_size,
-                               len(st.pkeys))
-                    for j in range(st.n_registered, full):
-                        self.pool.register(st.pkeys[j],
-                                           int(self.block_tables[i, j]))
-                    st.n_registered = max(st.n_registered, full)
-                if st.pf_off >= len(st.req.prompt):
-                    finishing.append(i)
+            with span("sched.prefill_launch"):
+                nxt, finishing = self._launch_chunk(lanes)
             if finishing:
-                toks = np.asarray(jax.device_get(nxt))  # one sync per launch
-                for i in finishing:
-                    st = self.slots[i]
-                    st.prefilling = False
-                    self.prefill_count += 1
-                    self._arm_lane(i, st.req, len(st.req.prompt))
-                    self._emit(events, i, int(toks[i]))
+                with span("sched.prefill_sync"):
+                    toks = np.asarray(jax.device_get(nxt))  # one sync per launch
+                with span("sched.emit"):
+                    for i in finishing:
+                        st = self.slots[i]
+                        st.prefilling = False
+                        self.prefill_count += 1
+                        self._arm_lane(i, st.req, len(st.req.prompt))
+                        self._emit(events, i, int(toks[i]))
                 # a prefill token may retire its request instantly; refill
                 # the freed lanes so they start prefilling next launch
-                self._assign_slots()
+                with span("sched.admit"):
+                    self._assign_slots()
+
+    def _launch_chunk(self, lanes: list[int]):
+        """Host prep and dispatch of one pooled chunk launch over the
+        prefilling `lanes`.  Returns the launch's (device) next tokens and
+        the lanes whose chunk completes their prompt."""
+        clen = {i: min(self.prefill_chunk,
+                       len(self.slots[i].req.prompt) - self.slots[i].pf_off)
+                for i in lanes}
+        bucket = prefill_bucket_for(max(clen.values()), self.buckets)
+        tokens = np.zeros((self.B, bucket), np.int32)
+        offset = np.zeros(self.B, np.int32)
+        n_valid = np.zeros(self.B, np.int32)
+        temp = np.zeros(self.B, np.float32)
+        top_k = np.ones(self.B, np.int32)
+        keys = np.zeros((self.B, 2), np.uint32)
+        for i in lanes:
+            st = self.slots[i]
+            tokens[i, :clen[i]] = st.req.prompt[st.pf_off:st.pf_off + clen[i]]
+            offset[i] = st.pf_off
+            n_valid[i] = clen[i]
+            temp[i] = st.req.temperature
+            top_k[i] = st.req.top_k
+            keys[i] = np.asarray(jax.random.PRNGKey(st.req.seed), np.uint32)
+        bt = ()
+        if self.pool is not None:
+            bs = self.spec.kv_block_size
+            for i in lanes:
+                st = self.slots[i]
+                for j in range(st.pf_off // bs,
+                               -(-(st.pf_off + clen[i]) // bs)):
+                    if self.block_tables[i, j] < 0:
+                        self.block_tables[i, j] = self._lane_alloc(st)
+            bt = (self._bt_device(),)
+        extra = ()
+        if self.spec.sampling:
+            extra = ({"temp": jnp.asarray(temp),
+                      "top_k": jnp.asarray(top_k),
+                      "key": jnp.asarray(keys)},)
+        nxt, self.cache = self.engine.prefill_chunk_step(bucket)(
+            self.params, self.cache, jnp.asarray(tokens),
+            jnp.asarray(offset), jnp.asarray(n_valid), *bt,
+            self.gather_key, *extra)
+        self.prefill_chunk_count += 1
+        self._pf_shapes.add(bucket)
+        self._max_pf_tokens = max(self._max_pf_tokens, bucket)
+        finishing = []
+        for i in lanes:
+            st = self.slots[i]
+            st.pf_off += clen[i]
+            if self.pool is not None and self.prefix_share:
+                # publish every prompt block this chunk completed (all
+                # chunk offsets are multiples of the chunk size from 0,
+                # so the block bytes are the canonical decomposition's)
+                full = min(st.pf_off // self.spec.kv_block_size,
+                           len(st.pkeys))
+                for j in range(st.n_registered, full):
+                    self.pool.register(st.pkeys[j],
+                                       int(self.block_tables[i, j]))
+                st.n_registered = max(st.n_registered, full)
+            if st.pf_off >= len(st.req.prompt):
+                finishing.append(i)
+        return nxt, finishing
 
     # -- the scheduler loop --------------------------------------------------
 
@@ -662,10 +699,12 @@ class ContinuousScheduler:
         (admission may also stream admitted requests' first tokens)."""
         events: list[TokenEvent] = []
         if self.prefill_chunk:
-            self._assign_slots()
+            with span("sched.admit"):
+                self._assign_slots()
             self._chunk_pass(events)
         else:
-            self._admit_blocking(events)
+            with span("sched.admit"):
+                self._admit_blocking(events)
         active = [i for i, s in enumerate(self.slots)
                   if s is not None and not s.prefilling]
         if not active:
@@ -684,34 +723,38 @@ class ContinuousScheduler:
                           self.spec.cache_len - int(self.pos[i]))
                 n_spec[i] = max(1, k_i)
         kmax = max(int(n_spec[i]) for i in active)
-        bt = ()
-        if self.pool is not None:
-            for i in active:
-                self._prepare_decode_blocks(i, int(n_spec[i]))
-            if self.pool.quant_horizon > 0 and self.pool.quant_cfg:
-                # quantized cold tier: idle cached prefix blocks re-encode
-                # into the core.quant wire format, freeing their hot block
-                self.engine.demote_cold_blocks(self.cache, self.pool,
-                                               self.step_count)
-            bt = (self._bt_device(),)
-        extra = ()
-        if self.spec.sampling:
-            extra = ({"temp": jnp.asarray(self.temp),
-                      "top_k": jnp.asarray(self.top_k),
-                      "key": jnp.asarray(self.keys)},)
-        if kmax == 1:
+        with span("sched.decode_launch"):
+            bt = ()
+            if self.pool is not None:
+                for i in active:
+                    self._prepare_decode_blocks(i, int(n_spec[i]))
+                if self.pool.quant_horizon > 0 and self.pool.quant_cfg:
+                    # quantized cold tier: idle cached prefix blocks re-encode
+                    # into the core.quant wire format, freeing their hot block
+                    self.engine.demote_cold_blocks(self.cache, self.pool,
+                                                   self.step_count)
+                bt = (self._bt_device(),)
+            extra = ()
+            if self.spec.sampling:
+                extra = ({"temp": jnp.asarray(self.temp),
+                          "top_k": jnp.asarray(self.top_k),
+                          "key": jnp.asarray(self.keys)},)
+            if kmax > 1:
+                return self._step_speculative(events, active, n_spec, kmax,
+                                              bt, extra)
             nxt, self.cache = self.engine.decode_step()(
                 self.params, self.cache, jnp.asarray(self.tok),
                 jnp.asarray(self.pos), *bt, self.gather_key, *extra)
+        with span("sched.decode_sync"):
             nxt = np.asarray(jax.device_get(nxt))
-            self.decode_launches += 1
-            self.step_count += 1
-            self.occupancy_sum += len(active)
+        self.decode_launches += 1
+        self.step_count += 1
+        self.occupancy_sum += len(active)
+        with span("sched.emit"):
             for slot_i in active:
                 self.pos[slot_i] += 1
                 self._emit(events, slot_i, int(nxt[slot_i]))
-            return events
-        return self._step_speculative(events, active, n_spec, kmax, bt, extra)
+        return events
 
     def _step_speculative(self, events: list, active: list[int],
                           n_spec: np.ndarray, kmax: int,
@@ -747,23 +790,25 @@ class ContinuousScheduler:
             jnp.asarray(n_spec), *bt, self.gather_key, *extra)
         self.verify_launches += 1
         self.spec_lane_steps += len(active)
-        tok_host, out_host = jax.device_get((tok_mat, outs))
+        with span("sched.decode_sync"):
+            tok_host, out_host = jax.device_get((tok_mat, outs))
         tok_host = np.asarray(tok_host)
         out_host = np.asarray(out_host)
         self.step_count += 1
         self.occupancy_sum += len(active)
-        for i in active:
-            k_i = int(n_spec[i])
-            a = 0  # accepted drafts: longest prefix the verifier agrees on
-            while (a < k_i - 1
-                   and int(out_host[i, a]) == int(tok_host[i, a + 1])):
-                a += 1
-            for j in range(a + 1):
-                self.pos[i] += 1
-                self.spec_tokens += 1
-                self._emit(events, i, int(out_host[i, j]))
-                if self.slots[i] is None:
-                    break  # EOS / budget retirement mid-window
+        with span("sched.emit"):
+            for i in active:
+                k_i = int(n_spec[i])
+                a = 0  # accepted drafts: longest prefix the verifier agrees on
+                while (a < k_i - 1
+                       and int(out_host[i, a]) == int(tok_host[i, a + 1])):
+                    a += 1
+                for j in range(a + 1):
+                    self.pos[i] += 1
+                    self.spec_tokens += 1
+                    self._emit(events, i, int(out_host[i, j]))
+                    if self.slots[i] is None:
+                        break  # EOS / budget retirement mid-window
         return events
 
     def run(self, max_steps: Optional[int] = None,
@@ -790,13 +835,21 @@ class ContinuousScheduler:
             # distinct prefill shapes this scheduler compiled: bucket
             # lengths when chunked (bounded by len(self.buckets)), distinct
             # prompt lengths when blocking (unbounded — the bug chunking
-            # fixes); bench_serve asserts on it in CI
+            # fixes); benchmarks/bench_serve.py asserts on it
             "prefill_traces": len(self._pf_shapes),
             # longest prompt-token stretch a single prefill launch processed
             # while live slots waited: the whole prompt under blocking
             # admission, at most one (padded) chunk under chunked admission
             "max_prefill_launch_tokens": self._max_pf_tokens,
             "tokens_generated": self.tokens_generated,
+            # time to first token, split and summed over requests (host
+            # seconds): queue_wait_s from submit to a slot (added at
+            # admission), prefill_wait_s from the slot to token 0 (added
+            # with token 0); first_tokens counts the token 0s, so the mean
+            # prefill wait is prefill_wait_s / first_tokens
+            "queue_wait_s": self.queue_wait_s,
+            "prefill_wait_s": self.prefill_wait_s,
+            "first_tokens": self.first_tokens,
             "slots": self.B,
             "mean_occupancy": (self.occupancy_sum / self.step_count
                                if self.step_count else 0.0),

@@ -61,6 +61,7 @@ from ..core.quant import (
     quantize_dequantize,
 )
 from ..models.transformer import Model
+from ..obs import program
 from ..optim import Optimizer, OptState
 
 
@@ -264,7 +265,8 @@ def _h(s: str) -> int:
 def make_jitted_train_step(model: Model, optimizer: Optimizer, mesh, n_micro: int = 1,
                            batch_pspec: Optional[dict] = None, donate: bool = True,
                            quantized_state: bool = False, **kw):
-    """Convenience: shard_map + jit the per-device step over `mesh`."""
+    """Convenience: shard_map + jit the per-device step over `mesh`, as the
+    program ``train_step``."""
     step = build_train_step(model, optimizer, n_micro=n_micro,
                             quantized_state=quantized_state, **kw)
     sspec = state_pspecs(
@@ -280,4 +282,5 @@ def make_jitted_train_step(model: Model, optimizer: Optimizer, mesh, n_micro: in
         out_specs=(sspec, {"loss": P(), "grad_norm": P(), "step": P()}),
         check_vma=False,
     )
-    return jax.jit(mapped, donate_argnums=(0,) if donate else ())
+    return jax.jit(program("train_step", mapped),
+                   donate_argnums=(0,) if donate else ())
