@@ -70,7 +70,7 @@ from jax.sharding import PartitionSpec as P
 
 from jax.lax import axis_size
 from ..kernels.ops import RowQuantWeight
-from ..obs import scoped
+from ..obs import scope, scoped
 from . import collectives as coll
 from .quant import (QuantConfig, QuantizedParam, dequantize, quantize,
                     unpack_codes, wire_unpack)
@@ -478,13 +478,22 @@ def _layer_keys(key: jax.Array, st: _LayerStatic) -> list:
     return [jax.random.fold_in(key, _stable_hash(n)) for n in st.names]
 
 
-@scoped("qsdp_gather")
-def _layer_encode_gather(shards, key: jax.Array, st: _LayerStatic) -> jax.Array:
-    keys = _layer_keys(key, st)
-    buf = coll.encode_wire([s.reshape(-1) for s in shards],
-                           st.gather_layout(), keys)
+def _layer_encode(shards, key: jax.Array, st: _LayerStatic) -> jax.Array:
+    """Encode half of the coalesced gather: this device's shards quantized
+    and serialized into one (layout.nbytes,) u8 buffer."""
+    return coll.encode_wire([s.reshape(-1) for s in shards],
+                            st.gather_layout(), _layer_keys(key, st))
+
+
+def _layer_gather(buf: jax.Array, st: _LayerStatic) -> jax.Array:
+    """The collective of the coalesced gather: (nbytes,) -> (P * nbytes,)."""
     pod = st.pod_axis if st.hierarchical else None
     return coll.gather_wire(buf, st.fsdp_axes, pod_axis=pod)
+
+
+@scoped("qsdp_gather")
+def _layer_encode_gather(shards, key: jax.Array, st: _LayerStatic) -> jax.Array:
+    return _layer_gather(_layer_encode(shards, key, st), st)
 
 
 @scoped("qsdp_gather")
@@ -564,6 +573,27 @@ def _qsdp_gather_layer_finish_bwd(st, key, cts):
 
 qsdp_gather_layer_finish.defvjp(_qsdp_gather_layer_finish_fwd,
                                 _qsdp_gather_layer_finish_bwd)
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class EncodedLayer:
+    """The encode half of one coalesced gather, kept: the u8 wire buffer of
+    the parameters `names` (full names, sorted), encoded and not yet
+    gathered.  Per device it is ``(1, 1, nbytes)``, or ``(stack, 1, 1,
+    nbytes)`` for a scan-over-layers group, the rest layout's cells with the
+    flat f32 shard replaced by its encoded bytes — 1/P of the layer on P
+    devices.  :meth:`QSDPEngine.gather_encoded` runs the other half."""
+
+    wire: jax.Array
+    names: tuple[str, ...]
+
+    def tree_flatten(self):
+        return (self.wire,), self.names
+
+    @classmethod
+    def tree_unflatten(cls, names, children):
+        return cls(children[0], names)
 
 
 # ---------------------------------------------------------------------------
@@ -721,6 +751,30 @@ class QSDPEngine:
         st = self._layer_static(tuple(f"{prefix}{k}" for k in names))
         shards = tuple(leaves[k].reshape(-1) for k in names)
         return names, st, shards
+
+    # -- encoded gather halves (serve: one fixed gather key) -------------------
+
+    def encode_layer(self, names: tuple[str, ...], leaves: dict[str, jax.Array],
+                     key: jax.Array) -> jax.Array:
+        """The encode half of :meth:`gather_layer` (or of :meth:`gather` for
+        one name) over the full param `names` (sorted): this device's
+        (nbytes,) u8 buffer, under the same per-tensor keys, so
+        :meth:`gather_encoded` later yields the bytes the fused gather would.
+        Only for names that :meth:`layer_coalesced` ships as one buffer."""
+        st = self._layer_static(names)
+        with scope("qsdp_gather"):
+            return _layer_encode(tuple(leaves[n].reshape(-1) for n in names),
+                                 key, st)
+
+    def gather_encoded(self, enc: EncodedLayer) -> dict[str, jax.Array]:
+        """The other half: all-gather one layer's encoded buffer and decode
+        it into each parameter's TP-local tensor, keyed by full name
+        (inference only: no VJP)."""
+        st = self._layer_static(enc.names)
+        with scope("qsdp_gather"):
+            wire = _layer_gather(enc.wire.reshape(-1), st)
+        fulls = _layer_decode(wire, st)
+        return {n: self._reshape_full(n, f) for n, f in zip(enc.names, fulls)}
 
     # -- code-form gather (serve/decode; no VJP — inference only) -------------
 

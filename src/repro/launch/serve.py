@@ -195,7 +195,8 @@ def run_continuous(setup, args) -> int:
           f"p95={np.percentile(lat, 95):.0f} steps, "
           f"ttft p95={np.percentile(ttft, 95):.3f}s "
           f"(mean queue wait {st['queue_wait_s'] / n_first:.3f}s, "
-          f"prefill wait {st['prefill_wait_s'] / n_first:.3f}s)")
+          f"prefill wait {st['prefill_wait_s'] / n_first:.3f}s), "
+          f"weight encodes {st['weight_encodes']}")
     if args.prefill_chunk:
         print(f"# chunked prefill: chunk={args.prefill_chunk} "
               f"buckets={sched.buckets} -> {st['prefill_chunks']} chunk "

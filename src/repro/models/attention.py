@@ -325,7 +325,7 @@ def chunk_new_kv(x: jax.Array, w: dict, cfg: AttnConfig, cos, sin):
 @scoped("attention")
 def chunk_attend(
     q_all: jax.Array,  # (B, Lq, Hp, hd) — all (padded) query heads
-    k_cache: jax.Array,  # (B, S_loc, n_kv, hd) — this rank's seq chunk,
+    k_cache: jax.Array,  # (B, S_loc, n_kv * hd) — this rank's seq chunk,
     v_cache: jax.Array,  # the chunk's own KV already written
     cfg: AttnConfig,
     q_pos: jax.Array,  # (B, Lq) per-slot-per-token query positions
@@ -355,6 +355,7 @@ def chunk_attend(
     else:
         s_loc = k_cache.shape[1]
         s_glob = rank * s_loc + jnp.arange(s_loc)
+    k_cache, v_cache = _split_heads(k_cache, hd), _split_heads(v_cache, hd)
     qr, k_cache, v_cache = _kv_major_q(q_all, k_cache, v_cache, cfg)
 
     # slot validity per query token: slot s holds p_s = q - ((q - s) mod W)
@@ -411,7 +412,7 @@ def slot_valid_mask(pos: jax.Array) -> jax.Array:
 # Paged block-pool addressing (vLLM-style; see serve/kv_pool.py)
 # ---------------------------------------------------------------------------
 #
-# The pool cache keeps the ring tensors' exact shape — (R, S_row, n_kv, hd)
+# The pool cache keeps the ring tensors' exact shape — (R, S_row, n_kv * hd)
 # per layer per rank, S_row the per-rank row length — but reinterprets each
 # row as `S_row // block_loc` physical blocks of block_loc tokens
 # (block_loc = block_size // tp: every block is sequence-sharded across all
@@ -429,17 +430,17 @@ def slot_valid_mask(pos: jax.Array) -> jax.Array:
 
 def paged_gather_kv(cache: jax.Array, block_tables: jax.Array,
                     block_loc: int) -> jax.Array:
-    """(R, S_row, n_kv, hd) pool -> (B, n_log * block_loc, n_kv, hd)
-    per-slot logical view through bt (B, n_log) physical block ids.
-    Unallocated table entries (< 0) clamp to block 0 — garbage the caller's
-    validity mask must exclude (it does: they can only cover positions
-    beyond the slot's write head)."""
-    r, s_row, nk, hd = cache.shape
+    """(R, S_row, ...) pool -> (B, n_log * block_loc, ...) per-slot logical
+    view through bt (B, n_log) physical block ids (the trailing axes pass
+    through).  Unallocated table entries (< 0) clamp to block 0 — garbage
+    the caller's validity mask must exclude (it does: they can only cover
+    positions beyond the slot's write head)."""
+    r, s_row, *tail = cache.shape
     bpr = s_row // block_loc
-    pool = cache.reshape(r * bpr, block_loc, nk, hd)
+    pool = cache.reshape(r * bpr, block_loc, *tail)
     b, n_log = block_tables.shape
     view = pool[jnp.clip(block_tables, 0, r * bpr - 1)]
-    return view.reshape(b, n_log * block_loc, nk, hd)
+    return view.reshape(b, n_log * block_loc, *tail)
 
 
 def paged_s_glob(window: int, block_size: int, block_loc: int) -> jax.Array:
@@ -472,6 +473,13 @@ def paged_slot(pos: jax.Array, window: int, block_size: int, block_loc: int,
     return row, seq, (o // block_loc) == rank
 
 
+def _split_heads(cache: jax.Array, hd: int) -> jax.Array:
+    """(B, S, n_kv * hd) cache view -> (B, S, n_kv, hd); a view already
+    split passes unchanged.  Split after the layer's view is gathered, so
+    only that view takes the head-major layout."""
+    return cache.reshape(*cache.shape[:2], -1, hd)
+
+
 def _kv_major_q(q_all: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                 cfg: AttnConfig):
     """Arrange the real query heads kv-major for the batched decode einsums.
@@ -497,7 +505,7 @@ def _kv_major_q(q_all: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 @scoped("attention")
 def decode_attend(
     q_all: jax.Array,  # (B, Hp, hd) — all (padded) query heads
-    k_cache: jax.Array,  # (B, S_loc, n_kv, hd) — this rank's seq chunk,
+    k_cache: jax.Array,  # (B, S_loc, n_kv * hd) — this rank's seq chunk,
     v_cache: jax.Array,  # current token's KV already written
     cfg: AttnConfig,
     pos: jax.Array,
@@ -540,6 +548,7 @@ def decode_attend(
     else:
         s_loc = k_cache.shape[1]
         s_glob = rank * s_loc + jnp.arange(s_loc)
+    k_cache, v_cache = _split_heads(k_cache, hd), _split_heads(v_cache, hd)
     qr, k_cache, v_cache = _kv_major_q(q_all, k_cache, v_cache, cfg)
 
     # slot validity: slot s (global) holds position p_s = pos - ((pos-s) mod W)

@@ -5,7 +5,10 @@ FSDP serving story (the paper's technique on the inference side): weights
 stay fully sharded at rest and are re-gathered — *quantized* — layer by
 layer inside every prefill/decode step.  Decode is therefore dominated by
 weight all-gather bytes, exactly the regime where QSDP's wire compression
-pays off most; the roofline benchmark quantifies this.
+pays off most; the roofline benchmark quantifies this.  Under a fixed
+gather key the encode half of those gathers is a constant:
+:meth:`DecodeModel.encode_weights` runs it once, and a step given its
+EncodedLayer tree runs only the gather and the decode.
 
 With ``DecodeSpec(rowquant_mlp=True)`` the dense-MLP weights skip the
 dequant step entirely: the gathered wire codes are reshaped (K, N) with
@@ -15,15 +18,21 @@ the fused ``kernels.ops.rowquant_matmul`` kernel (see
 
 Cache layouts (global shapes; per-device views inside shard_map):
 
-  attention KV  (L, B, S, n_kv, hd)   P(None, batch?, "model", None, None)
+  attention KV  (L, B, S, n_kv * hd)  P(None, batch?, "model", None)
                 ring-buffered along S (full cache == ring that never wraps;
-                sliding-window long-context == ring of window size)
+                sliding-window long-context == ring of window size).  The
+                heads and head_dim share the last axis: with head_dim
+                minor (64 < the TPU's 128-lane tile) the program held the
+                whole pool in a padded layout, copied in and out of every
+                step at twice its size; a last axis of n_kv * hd keeps the
+                stored layout the one the writes use.  Attention splits it
+                back into heads after gathering a layer's view.
   mamba conv    (L, B, K-1, tp * Cc)  P(None, batch?, None, "model")
                 with Cc = d_inner_local + 2N (each rank stores its own
                 slice; the 2N B/C section is per-rank replicated state)
   mamba ssm     (L, B, H, P, N)       P(None, batch?, "model", None, None)
   hybrid        mamba states (all layers) + per-group shared-block KV
-                (G, B, S, n_kv, hd)
+                (G, B, S, n_kv * hd)
   audio         decoder self KV ring + static encoder cross KV
                 (L, B, S_enc, n_kv, hd) + enc_len scalar
 
@@ -40,6 +49,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..core.qsdp import EncodedLayer
 from ..core.quant import QuantizedParam
 from ..obs import scoped
 from . import attention as attn_mod
@@ -54,6 +64,10 @@ Cache = dict[str, Any]
 # dense-MLP weights that may stay in wire-code form through swiglu_mlp
 # (rowquant decode and serve.engine.prepare_wire_params share this list)
 ROWQUANT_MLP = ("w_gate", "w_up", "w_down")
+
+# key, inside a layer group, of the group's EncodedLayer in a served tree
+# (DecodeModel.encode_weights): "layers/" + ENCODED
+ENCODED = "@encoded"
 
 # families whose prompts may prefill chunk-at-a-time into the pool cache
 # (pure attention stacks; modality/state caches still prefill whole-prompt)
@@ -212,11 +226,11 @@ class DecodeModel:
             # paged: rows are pool storage, not lanes — never batch-sharded
             rows = sp.pool_rows() if sp.paged else B
             rax = None if sp.paged else bax
-            shp = (layers, rows, s, m.acfg.n_kv, cfg.head_dim)
+            shp = (layers, rows, s, m.acfg.n_kv * cfg.head_dim)
             structs[prefix + "k"] = jax.ShapeDtypeStruct(shp, jnp.bfloat16)
             structs[prefix + "v"] = jax.ShapeDtypeStruct(shp, jnp.bfloat16)
-            specs[prefix + "k"] = P(None, rax, "model", None, None)
-            specs[prefix + "v"] = P(None, rax, "model", None, None)
+            specs[prefix + "k"] = P(None, rax, "model", None)
+            specs[prefix + "v"] = P(None, rax, "model", None)
 
         if cfg.arch_type in ("dense", "vlm", "moe"):
             kv("", cfg.n_layers, sp.cache_len)
@@ -289,13 +303,13 @@ class DecodeModel:
         The per-token sampling key is fold_in(slot key, pos + 1) — a pure
         function of the REQUEST's own key and position, so sampled output
         is reproducible across runs and across batch compositions."""
-        m, cfg = self.m, self.m.cfg
+        cfg = self.m.cfg
         pos = jnp.asarray(pos, jnp.int32)
         if pos.ndim == 0:
             pos = jnp.broadcast_to(pos, tokens.shape)
         if self.spec.paged and block_tables is None:
             raise ValueError("paged DecodeSpec: decode_fn needs block_tables")
-        emb = m.engine.gather("embed", params["embed"], key)
+        emb = self._gather_leaf(params, "embed", key)
         x = L.embed_vocab_parallel(tokens[:, None], emb)[:, 0]  # (B, d)
 
         cos, sin = self._decode_rope(pos)
@@ -315,9 +329,9 @@ class DecodeModel:
         else:
             raise ValueError(cfg.arch_type)
 
-        fn = m.engine.gather("final_norm", params["final_norm"], key)
+        fn = self._gather_leaf(params, "final_norm", key)
         x = L.rms_norm(x, fn, cfg.norm_eps)
-        head = emb if cfg.tie_embeddings else m.engine.gather("lm_head", params["lm_head"], key)
+        head = emb if cfg.tie_embeddings else self._gather_leaf(params, "lm_head", key)
         logits = L.vocab_parallel_logits(x, head)
         nxt = self._sample(logits, head.shape[0], sample, pos + 1,
                            valid=attn_mod.slot_valid_mask(pos))
@@ -360,9 +374,9 @@ class DecodeModel:
     @scoped("kv_write")
     def _write_token_kv(self, kc_all, vc_all, layer, k1, v1, pos,
                         block_tables=None):
-        """Write this token's KV into the scan-carried stacked cache
-        (L, B, S_loc, n_kv, hd) at (layer, b, ring slot of pos[b]) — a
-        token-sized gather + scatter per layer (~KB) instead of re-emitting
+        """Write this token's KV (B, n_kv, hd) into the scan-carried stacked
+        cache (L, B, S_loc, n_kv * hd) at (layer, b, ring slot of pos[b]) —
+        a token-sized gather + scatter per layer (~KB) instead of re-emitting
         the whole cache as scan ys (§Perf P2-1).  pos is (B,): each batch
         slot writes its OWN ring slot, so interleaved requests at different
         positions never touch each other's cache lines.
@@ -380,6 +394,7 @@ class DecodeModel:
         masked-out lanes redirect to the out-of-range row R and are DROPPED
         — same determinism argument as the chunk path below."""
         b = k1.shape[0]
+        k1, v1 = k1.reshape(b, -1), v1.reshape(b, -1)
         if block_tables is not None:
             bl_loc = self.spec.kv_block_size // self.tp
             row, seq, is_mine = attn_mod.paged_slot(
@@ -395,7 +410,7 @@ class DecodeModel:
         s_loc = kc_all.shape[2]
         idx, is_mine = attn_mod.ring_slot(pos, self.spec.cache_len, s_loc)
         bi = jnp.arange(b)
-        mine = (is_mine & attn_mod.slot_valid_mask(pos))[:, None, None]
+        mine = (is_mine & attn_mod.slot_valid_mask(pos))[:, None]
         new_k = jnp.where(mine, k1.astype(kc_all.dtype), kc_all[layer, bi, idx])
         new_v = jnp.where(mine, v1.astype(vc_all.dtype), vc_all[layer, bi, idx])
         kc_all = kc_all.at[layer, bi, idx].set(new_k)
@@ -443,15 +458,26 @@ class DecodeModel:
         whose buckets tile their rows stay in code form
         (QSDPEngine.gather_rowquant_wire -> rowquant_matmul), everything
         else dequantizes densely through the bits 2-8 kernels
-        (QSDPEngine.gather_wire_dequant)."""
+        (QSDPEngine.gather_wire_dequant).
+
+        A layer that arrives as an EncodedLayer (under ``ENCODED``, from
+        :meth:`encode_weights`) was encoded once under this same `lkey`:
+        only its gather and decode run here.  A QuantizedParam leaf beside
+        it overrides its copy of that name (the speculative draft's low-bit
+        codes over the served layer)."""
         m = self.m
+        enc = [n for n in names if isinstance(lw[n], EncodedLayer)]
         wire = [n for n in names if isinstance(lw[n], QuantizedParam)]
         rq = [n for n in names
-              if n not in wire
+              if n not in wire and n not in enc
               and self.spec.rowquant_mlp and mlp == "dense" and n in self._ROWQUANT_MLP]
         out = m.engine.gather_layer(
-            f"{prefix}/", {n: lw[n] for n in names if n not in rq and n not in wire},
+            f"{prefix}/", {n: lw[n] for n in names
+                           if n not in rq and n not in wire and n not in enc},
             lkey)
+        pl = len(prefix) + 1
+        for n in enc:
+            out.update({k[pl:]: v for k, v in m.engine.gather_encoded(lw[n]).items()})
         for n in wire:
             if (n in self._ROWQUANT_MLP
                     and m.engine.rowquant_wire_eligible(f"{prefix}/{n}", lw[n])):
@@ -460,6 +486,68 @@ class DecodeModel:
                 out[n] = m.engine.gather_wire_dequant(f"{prefix}/{n}", lw[n])
         for n in rq:
             out[n] = m.engine.gather_rowquant(f"{prefix}/{n}", lw[n], lkey)
+        return out
+
+    def _gather_leaf(self, params, name, key):
+        """Gather a non-layer leaf (embed, final_norm, lm_head) under the
+        step key: only the gather and decode when it arrives encoded."""
+        v = params[name]
+        if isinstance(v, EncodedLayer):
+            return self.m.engine.gather_encoded(v)[name]
+        return self.m.engine.gather(name, v, key)
+
+    # ------------------------------------------------------------------
+    # Served weights encoded once, for a fixed gather key
+    # ------------------------------------------------------------------
+
+    def encoded_groups(self, params: Params) -> dict[str, tuple[str, ...]]:
+        """What :meth:`encode_weights` encodes: {served-tree key: full
+        names}.  A layer group is encoded when every step program gathers
+        its layer i under ``fold_in(key, i)`` (the attention stacks, the
+        pure-SSM stack, the audio decoder) and rowquant decode does not
+        split it; its raw leaves (QuantizedParam leaves are already codes)
+        go into one buffer per layer under ``"<group>/" + ENCODED``.  The
+        non-layer leaves the steps gather under the key itself keep their
+        names.  Only what the engine ships as one coalesced buffer
+        (``QSDPEngine.layer_coalesced``) is encoded; the rest stays raw and
+        keeps its per-step gather."""
+        eng, arch = self.m.engine, self.m.cfg.arch_type
+        stacks = {"hybrid": (), "audio": ("dec",)}.get(arch, ("layers",))
+        groups = {}
+        for prefix in () if self.spec.rowquant_mlp else stacks:
+            names = tuple(sorted(n for n, v in params.items()
+                                 if n.startswith(prefix + "/")
+                                 and not isinstance(v, QuantizedParam)))
+            if names and eng.layer_coalesced(names):
+                groups[f"{prefix}/{ENCODED}"] = names
+        for n in ("embed", "final_norm", "lm_head"):
+            if (n in params and not isinstance(params[n], QuantizedParam)
+                    and eng.layer_coalesced((n,))):
+                groups[n] = (n,)
+        return groups
+
+    def encode_weights(self, params: Params, key: jax.Array) -> Params:
+        """Per-device: the served tree for the fixed gather `key` — each of
+        :meth:`encoded_groups` replaced by its EncodedLayer, encoded under
+        exactly the keys the step programs fold (``fold_in(key, i)`` for
+        layer i, `key` for a non-layer leaf), every other leaf as it was.
+        The steps then run only the gather and decode of those bytes, so
+        their tokens are bit-identical to the per-step gather's."""
+        eng = self.m.engine
+        groups = self.encoded_groups(params)
+        covered = {n for names in groups.values() for n in names}
+        out = {n: v for n, v in params.items() if n not in covered}
+        for tkey, names in groups.items():
+            leaves = {n: params[n] for n in names}
+            lead = leaves[names[0]].shape[:-1]
+            if tkey in names:
+                wire = eng.encode_layer(names, leaves, key)
+            else:
+                wire = lax.map(
+                    lambda inp: eng.encode_layer(
+                        names, inp[1], jax.random.fold_in(key, inp[0])),
+                    (jnp.arange(lead[0]), leaves))
+            out[tkey] = EncodedLayer(wire.reshape(*lead, -1), names)
         return out
 
     def _decode_attn_stack(self, params, prefix, x, cache, pos, cos, sin, key, mlp,
@@ -531,7 +619,7 @@ class DecodeModel:
         n_spec = jnp.asarray(n_spec, jnp.int32)
         mlp = "moe" if cfg.is_moe else "dense"
 
-        emb = m.engine.gather("embed", params["embed"], key)
+        emb = self._gather_leaf(params, "embed", key)
         # (K, B, d): embed is an elementwise take + psum, so embedding all
         # K tokens at once is bit-identical to decode_fn's per-token embed
         xs = jnp.moveaxis(L.embed_vocab_parallel(tokens, emb), 1, 0)
@@ -569,9 +657,9 @@ class DecodeModel:
             layer_body, (xs, cache["k"], cache["v"]), (jnp.arange(nl), grp))
         cache = dict(cache, k=k_new, v=v_new)
 
-        fn = m.engine.gather("final_norm", params["final_norm"], key)
-        head = emb if cfg.tie_embeddings else m.engine.gather(
-            "lm_head", params["lm_head"], key)
+        fn = self._gather_leaf(params, "final_norm", key)
+        head = emb if cfg.tie_embeddings else self._gather_leaf(
+            params, "lm_head", key)
 
         def out_body(_, inp2):
             x, pj = inp2
@@ -592,7 +680,8 @@ class DecodeModel:
     def _write_chunk_kv(self, kc_all, vc_all, layer, k1, v1, pos, n_valid,
                         block_tables=None):
         """Write one chunk's KV into the stacked pool cache at each slot's
-        own ring offsets.  k1/v1 (B, Lq, n_kv, hd); pos (B, Lq) global
+        own ring offsets.  k1/v1 (B, Lq, n_kv, hd), stored as
+        (B, Lq, n_kv * hd); pos (B, Lq) global
         positions; n_valid (B,) valid tokens per slot (0 = lane not
         prefilling).
 
@@ -606,6 +695,7 @@ class DecodeModel:
         padded tokens or non-prefilling lanes, so live decode slots' (and
         dead lanes') ring bytes are untouched."""
         b, lq = pos.shape
+        k1, v1 = k1.reshape(b, lq, -1), v1.reshape(b, lq, -1)
         tok_valid = jnp.arange(lq)[None, :] < n_valid[:, None]
         if block_tables is not None:
             # paged: targets map through the lane's table to (pool row, row
@@ -714,7 +804,7 @@ class DecodeModel:
         Same gather key / per-layer fold_in as prefill_fn and decode_fn, so
         the dequantized weights are bit-identical to the whole-prompt path.
         Supported for CHUNKED_PREFILL_ARCHS (pure attention stacks)."""
-        m, cfg = self.m, self.m.cfg
+        cfg = self.m.cfg
         if cfg.arch_type not in CHUNKED_PREFILL_ARCHS:
             raise NotImplementedError(
                 f"chunked prefill supports {CHUNKED_PREFILL_ARCHS}, "
@@ -724,18 +814,18 @@ class DecodeModel:
         b, lq = tokens.shape
         offset = jnp.asarray(offset, jnp.int32)
         n_valid = jnp.asarray(n_valid, jnp.int32)
-        emb = m.engine.gather("embed", params["embed"], key)
+        emb = self._gather_leaf(params, "embed", key)
         x = L.embed_vocab_parallel(tokens, emb)  # (B, Lq, d)
         pos = offset[:, None] + jnp.arange(lq, dtype=jnp.int32)[None, :]
         cos, sin = L.rope_cos_sin(pos, cfg.head_dim, cfg.rope_theta)
         x, cache = self._chunk_attn_stack(
             params, "layers", x, cache, pos, n_valid, cos, sin, key,
             mlp="moe" if cfg.is_moe else "dense", block_tables=block_tables)
-        fn = m.engine.gather("final_norm", params["final_norm"], key)
+        fn = self._gather_leaf(params, "final_norm", key)
         last = jnp.clip(n_valid - 1, 0, lq - 1)
         h = L.rms_norm(x[jnp.arange(b), last], fn, cfg.norm_eps)
-        head = emb if cfg.tie_embeddings else m.engine.gather(
-            "lm_head", params["lm_head"], key)
+        head = emb if cfg.tie_embeddings else self._gather_leaf(
+            params, "lm_head", key)
         logits = L.vocab_parallel_logits(h, head)
         nxt = self._sample(logits, head.shape[0], sample, offset + n_valid,
                            valid=n_valid > 0)
@@ -765,7 +855,7 @@ class DecodeModel:
             x, conv_all, ssm_all = carry
             idx, lw = inp
             lkey = jax.random.fold_in(key, key_base + idx)
-            w = m.engine.gather_layer(f"{prefix}/", {n: lw[n] for n in names}, lkey)
+            w = self._gather_layer_w(prefix, names, lw, lkey)
             li = layer_offset + idx
             cv = lax.dynamic_index_in_dim(conv_all, li, 0, keepdims=False)
             st = lax.dynamic_index_in_dim(ssm_all, li, 0, keepdims=False)
@@ -776,7 +866,7 @@ class DecodeModel:
                 ssm_all, st[None].astype(ssm_all.dtype), li, 0)
             return (x, conv_all, ssm_all), None
 
-        nl = grp[names[0]].shape[0]
+        nl = jax.tree.leaves(grp)[0].shape[0]
         (x, conv_new, ssm_new), _ = lax.scan(
             body, (x, conv, ssm), (jnp.arange(nl), grp))
         if not external:
@@ -866,7 +956,7 @@ class DecodeModel:
             x = x + L.swiglu_mlp(h, w["w_gate"], w["w_up"], w["w_down"])
             return (x, kc_all, vc_all), None
 
-        nl = grp[names[0]].shape[0]
+        nl = jax.tree.leaves(grp)[0].shape[0]
         (x, k_new, v_new), _ = lax.scan(
             body, (x, cache["k"], cache["v"]),
             (jnp.arange(nl), grp, cache["ck"], cache["cv"]))
@@ -892,7 +982,7 @@ class DecodeModel:
         b, s = tokens.shape
         if self.m.cfg.has_attention:
             assert self.spec.cache_len >= s, "prefill prompt exceeds the cache ring"
-        emb = m.engine.gather("embed", params["embed"], key)
+        emb = self._gather_leaf(params, "embed", key)
         x = L.embed_vocab_parallel(tokens, emb)
         if cfg.arch_type == "vlm":
             x = jnp.where(batch["vision_mask"][..., None],
@@ -914,24 +1004,25 @@ class DecodeModel:
         else:
             raise ValueError(cfg.arch_type)
 
-        fn = m.engine.gather("final_norm", params["final_norm"], key)
+        fn = self._gather_leaf(params, "final_norm", key)
         h = L.rms_norm(x[:, -1], fn, cfg.norm_eps)
-        head = emb if cfg.tie_embeddings else m.engine.gather("lm_head", params["lm_head"], key)
+        head = emb if cfg.tie_embeddings else self._gather_leaf(params, "lm_head", key)
         logits = L.vocab_parallel_logits(h, head)
         nxt = self._sample(logits, head.shape[0], sample,
                            jnp.full((b,), s, jnp.int32))
         return nxt.astype(jnp.int32), cache
 
     def _slice_seq(self, kv: jax.Array) -> jax.Array:
-        """(B, S, n_kv, hd) full-seq KV -> this rank's S_loc ring chunk
-        (zero-padded when the prompt is shorter than the ring)."""
+        """(B, S, n_kv, hd) full-seq KV -> this rank's S_loc ring chunk in
+        the cache's (B, S_loc, n_kv * hd) form (zero-padded when the prompt
+        is shorter than the ring)."""
         rank = lax.axis_index("model")
         b, s, nk, hd = kv.shape
         pad = self.spec.cache_len - s
         if pad:
             kv = jnp.pad(kv, ((0, 0), (0, pad), (0, 0), (0, 0)))
         return lax.dynamic_slice(kv, (0, rank * self.s_loc, 0, 0),
-                                 (b, self.s_loc, nk, hd))
+                                 (b, self.s_loc, nk, hd)).reshape(b, self.s_loc, -1)
 
     def _prefill_attn_layer(self, x, w, cos, sin, positions, mlp):
         m, cfg = self.m, self.m.cfg
@@ -979,14 +1070,14 @@ class DecodeModel:
         def body(x, inp):
             idx, lw = inp
             lkey = jax.random.fold_in(key, key_base + idx)
-            w = m.engine.gather_layer(f"{prefix}/", {n: lw[n] for n in names}, lkey)
+            w = self._gather_layer_w(prefix, names, lw, lkey)
             h = L.rms_norm(x, w["pre_norm"], cfg.norm_eps)
             mw = {k: v for k, v in w.items() if k != "pre_norm"}
             y, (cx, cbc, hf) = mamba_mod.mamba2_block(h, mw, m.mcfg, return_state=True)
             conv = jnp.concatenate([cx, cbc.astype(cx.dtype)], axis=-1).astype(jnp.float32)
             return x + y, (conv, hf.astype(jnp.float32))
 
-        nl = grp[names[0]].shape[0]
+        nl = jax.tree.leaves(grp)[0].shape[0]
         x, (conv, ssm) = lax.scan(jax.checkpoint(body), x, (jnp.arange(nl), grp))
         return x, conv, ssm
 
@@ -1044,7 +1135,7 @@ class DecodeModel:
         def body(x, inp):
             idx, lw = inp
             lkey = jax.random.fold_in(key, idx)
-            w = m.engine.gather_layer("dec/", {n: lw[n] for n in names}, lkey)
+            w = self._gather_layer_w("dec", names, lw, lkey)
             # self-attn with cache slice
             h = L.rms_norm(x, w["attn_norm"], cfg.norm_eps)
             a, (kf, vf) = attn_mod.self_attention(h, w, m.acfg, cos, sin, positions,
@@ -1072,6 +1163,6 @@ class DecodeModel:
             vc = self._slice_seq(vf).astype(jnp.bfloat16)
             return x, (kc, vc, ck.astype(jnp.bfloat16), cvv.astype(jnp.bfloat16))
 
-        nl = grp[names[0]].shape[0]
+        nl = jax.tree.leaves(grp)[0].shape[0]
         x, (k, v, ck, cv) = lax.scan(jax.checkpoint(body), x, (jnp.arange(nl), grp))
         return x, {"k": k, "v": v, "ck": ck, "cv": cv}

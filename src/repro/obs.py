@@ -16,7 +16,8 @@ list:
 * :func:`program` names a jitted program: its compiled module, and its
   events on a trace's ``XLA Modules`` line, read ``jit_<name>``
   (``train_step``, ``serve_decode``, ``serve_prefill_chunk``,
-  ``serve_verify``, ``serve_prefill``).
+  ``serve_verify``, ``serve_prefill``, and ``serve_encode_weights``, which
+  runs once when a scheduler is built).
 * :func:`span` (``jax.profiler.TraceAnnotation``) labels host work: the
   scheduler's admission, launches, syncs and token bookkeeping
   (``repro.sched.*``).  With no profiler running it costs about a

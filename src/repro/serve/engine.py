@@ -5,6 +5,14 @@ math.  Decoding re-gathers quantized weights layer-by-layer every step —
 FSDP-style serving — so step latency is collective-bound and QSDP's wire
 compression directly reduces it (see benchmarks/fig4_bandwidth_model.py).
 
+A fixed gather key makes the encoded weights a constant:
+:func:`encode_served_weights` runs the encode half of every such gather
+ONCE (program ``serve_encode_weights``) and returns the served tree, whose
+layers are EncodedLayer buffers (each device's u8 codes, not yet
+gathered).  Given that tree, every step program only gathers and decodes
+(the continuous scheduler serves this way); given raw params it still
+encodes per step (``generate``, whose keys may change every step).
+
 With ``DecodeSpec(rowquant_mlp=True)`` the dense-MLP weights additionally
 *stay in wire-code form* after the gather: the fused
 ``kernels.ops.rowquant_matmul`` Pallas kernel consumes the gathered u8
@@ -30,6 +38,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax import shard_map
 
+from ..core.qsdp import EncodedLayer
 from ..core.quant import (QuantConfig, QuantizedParam, qparam_decode,
                           qparam_encode, qparam_split_stack)
 from ..models.decode import ROWQUANT_MLP, DecodeModel, DecodeSpec, make_decode_spec
@@ -118,15 +127,38 @@ def make_draft_params(model: Model, params: dict, draft_bits: int) -> dict:
 
 def wire_param_pspecs(model: Model, params: dict) -> dict:
     """Per-leaf PartitionSpecs for a params dict that may mix dense rest
-    leaves and (possibly stack-split) QuantizedParam wire leaves."""
+    leaves, (possibly stack-split) QuantizedParam wire leaves and the
+    EncodedLayer buffers of a served tree."""
     out = {}
     base = ("model", model.ms.fsdp_axes, None)
     for name, v in params.items():
-        if isinstance(v, QuantizedParam):
+        if isinstance(v, (QuantizedParam, EncodedLayer)):
             out[name] = P(None, *base) if v.wire.ndim == 4 else P(*base)
         else:
             out[name] = model.specs[name].rest_pspec(model.ms)
     return out
+
+
+def encode_served_weights(model: Model, mesh, spec: DecodeSpec, params: dict,
+                          key: jax.Array) -> dict:
+    """The served tree for the fixed gather `key`: one program
+    (``serve_encode_weights``) runs ``DecodeModel.encode_weights`` on every
+    device, so each layer group and non-layer leaf the steps gather becomes
+    an EncodedLayer holding that device's codes under the keys the steps
+    fold.  Step programs built for this tree (``ServeEngine(...,
+    params=tree)``) take it in place of `params` and only gather and decode
+    it; their tokens are bit-identical to the per-step gather's."""
+    dm = DecodeModel(model, spec)
+    in_ps = wire_param_pspecs(model, params)
+    groups = dm.encoded_groups(params)
+    covered = {n for names in groups.values() for n in names}
+    base = ("model", model.ms.fsdp_axes, None)
+    out_ps = {n: p for n, p in in_ps.items() if n not in covered}
+    for tkey, names in groups.items():
+        out_ps[tkey] = P(None, *base) if params[names[0]].ndim == 4 else P(*base)
+    fn = shard_map(dm.encode_weights, mesh=mesh, in_specs=(in_ps, P()),
+                   out_specs=out_ps, check_vma=False)
+    return jax.jit(program("serve_encode_weights", fn))(params, key)
 
 
 def prefill_bucket_sizes(chunk: int, n_buckets: int, cache_len: int
@@ -342,7 +374,7 @@ class ServeEngine:
         its block_loc-token slice of every block), so in the global arrays
         block `bid` = row ``bid // bpr``, seq positions
         ``rank * s_loc + (bid % bpr) * block_loc + [0, block_loc)`` per
-        rank — token order inside the (L, block_size, n_kv, hd) view is the
+        rank — token order inside the (L, block_size, n_kv * hd) view is the
         natural position order.  These run OUTSIDE shard_map between steps
         (cold-tier demote/rehydrate, COW forks); they are off the decode
         hot path."""

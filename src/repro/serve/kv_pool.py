@@ -2,7 +2,7 @@
 
 The serve stack's KV cache is a pool of fixed-size blocks instead of one
 private ring per slot.  Device tensors keep the ring's exact layout — the
-pool cache is ``(L, R, cache_len, n_kv, hd)`` sequence-sharded over the
+pool cache is ``(L, R, cache_len, n_kv * hd)`` sequence-sharded over the
 model axis, reinterpreted per rank as ``R * blocks_per_row`` physical
 blocks of ``block_size // tp`` tokens each (see
 ``models.attention.paged_gather_kv``) — so a physical block is addressed
@@ -98,7 +98,7 @@ class ColdBlock:
     """One demoted block: wire bytes for k and v + enough to decode."""
     k_wire: np.ndarray  # (wire_bytes,) u8
     v_wire: np.ndarray
-    shape: tuple  # (L, block_size, n_kv, hd) — the hot bf16 shape
+    shape: tuple  # (L, block_size, n_kv * hd) — the hot bf16 shape
     cfg: QuantConfig
 
     @property
@@ -107,7 +107,7 @@ class ColdBlock:
 
 
 def encode_block(k: np.ndarray, v: np.ndarray, cfg: QuantConfig) -> ColdBlock:
-    """(L, bs, n_kv, hd) bf16/f32 block pair -> wire-format ColdBlock."""
+    """(L, bs, n_kv * hd) bf16/f32 block pair -> wire-format ColdBlock."""
     shape = tuple(k.shape)
     kw = np.asarray(wire_pack(quantize(jnp.asarray(k, jnp.float32), cfg)))
     vw = np.asarray(wire_pack(quantize(jnp.asarray(v, jnp.float32), cfg)))

@@ -52,7 +52,13 @@ scripts/check_serve_sched.py):
   makes the dequantized weights a function of the gather key, so the
   scheduler uses ONE ``gather_key`` for every prefill chunk and decode
   step.  Chunked prefill folds the same per-layer keys as whole-prompt
-  prefill, so both paths dequantize bit-identical weights.
+  prefill, so both paths dequantize bit-identical weights.  With the key
+  fixed the encoded weights are a constant, so the scheduler encodes them
+  ONCE, when it is built (``serve.engine.encode_served_weights``, counted
+  by ``stats()["weight_encodes"]``), and serves that encoded tree in place
+  of the f32 params: each step only gathers and decodes it, and its tokens
+  are bit-identical to the per-step gather of
+  ``ServeEngine.generate(..., fold_step_keys=False)``.
 * **Reproducible sampling** — per-request sampling streams are keyed by
   ``fold_in(PRNGKey(request.seed), position)``, a pure function of the
   request itself, so temperature/top-k outputs are identical across runs
@@ -75,8 +81,9 @@ from jax.sharding import PartitionSpec as P
 from ..models.decode import CHUNKED_PREFILL_ARCHS, DecodeSpec
 from ..models.transformer import Model
 from ..obs import span
-from .engine import (ServeEngine, make_draft_params, make_sample_params,
-                     prefill_bucket_for, prefill_bucket_sizes)
+from .engine import (ServeEngine, encode_served_weights, make_draft_params,
+                     make_sample_params, prefill_bucket_for,
+                     prefill_bucket_sizes)
 from .kv_pool import BlockPool, PoolExhausted, prefix_keys
 
 
@@ -161,6 +168,8 @@ class ContinuousScheduler:
         as for :class:`ServeEngine`; ``spec.batch_global`` is the slot-pool
         size B.  Set ``spec.sampling=True`` to serve temperature/top-k
         requests (greedy requests still take the bit-exact greedy path).
+        ``self.params`` is the served tree the step programs take: `params`
+        encoded once under `gather_key` (see module docstring).
     gather_key:
         the ONE weight-gather key used for every prefill and decode step
         (see module docstring).  Defaults to PRNGKey(0).
@@ -188,12 +197,16 @@ class ContinuousScheduler:
         self.model = model
         self.mesh = mesh
         self.spec = spec
-        self.params = params
         self.B = spec.batch_global
         self.gather_key = (gather_key if gather_key is not None
                            else jax.random.PRNGKey(0))
         self.batch_builder = batch_builder or self._default_batch
-        self.engine = ServeEngine(model, mesh, spec, params=params)
+        # the fixed key makes the encoded weights a constant: encode them
+        # once, and serve the encoded tree in place of the f32 params
+        self.params = encode_served_weights(model, mesh, spec, params,
+                                            self.gather_key)
+        self.weight_encodes = 1
+        self.engine = ServeEngine(model, mesh, spec, params=self.params)
         self.prefill_chunk = int(prefill_chunk)
         if self.prefill_chunk < 0:
             raise ValueError(
@@ -220,19 +233,22 @@ class ContinuousScheduler:
         self._pf_spec = dataclasses.replace(spec, batch_global=1,
                                             batch_sharded=False)
         self.prefill_engine = ServeEngine(model, mesh, self._pf_spec,
-                                          params=params)
+                                          params=self.params)
 
         # self-speculative decoding (spec.draft_depth > 1): a low-bit draft
         # engine shares THIS scheduler's cache and reads the wire codes
         # already resident for QSDP (make_draft_params re-encodes only raw
         # leaves, once, host-side); the serving-precision engine verifies
         # every drafted token before it is committed, so streams stay
-        # bit-identical to non-speculative decode
+        # bit-identical to non-speculative decode.  The draft tree is the
+        # served tree with the draft's own low-bit leaves over it, so the
+        # two share every encoded byte the draft does not replace.
         self.draft_engine: Optional[ServeEngine] = None
         self.draft_params: Optional[dict] = None
         if spec.speculative:
-            self.draft_params = make_draft_params(model, params,
-                                                  spec.draft_bits)
+            draft = make_draft_params(model, params, spec.draft_bits)
+            self.draft_params = self.params | {
+                n: v for n, v in draft.items() if v is not params[n]}
             self.draft_engine = ServeEngine(model, mesh, spec,
                                             params=self.draft_params)
 
@@ -842,6 +858,9 @@ class ContinuousScheduler:
             # admission, at most one (padded) chunk under chunked admission
             "max_prefill_launch_tokens": self._max_pf_tokens,
             "tokens_generated": self.tokens_generated,
+            # times the served weights were encoded: once, when the
+            # scheduler was built, however many steps run
+            "weight_encodes": self.weight_encodes,
             # time to first token, split and summed over requests (host
             # seconds): queue_wait_s from submit to a slot (added at
             # admission), prefill_wait_s from the slot to token 0 (added

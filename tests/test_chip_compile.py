@@ -86,3 +86,50 @@ def test_rowquant_matmul_compiles_for_v5e(one_chip, no_persistent_cache, m, n_se
     meta = jax.ShapeDtypeStruct((k, n_seg), jnp.float32, sharding=one_chip)
     fn = jax.jit(lambda x, c, s, z: ops.rowquant_matmul(x, c, s, z, interpret=False))
     assert "tpu_custom_call" in _compiled_text(fn, x, codes, meta, meta)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_paged_steps_keep_the_kv_pool_in_place(topo, no_persistent_cache, program):
+    """The paged serving steps update the KV pool where it lies: the
+    compiled step holds no copy of a whole pool.  With head_dim (64) as the
+    stored minor axis the chip's compiler kept the pool in a layout padded
+    to the 128-lane tile, and copied each pool in and out of every step at
+    twice its size; the pool's (L, R, S, n_kv * hd) form leaves no copy."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core.qsdp import MeshSpec, QSDPConfig
+    from repro.launch.mesh import make_mesh
+    from repro.models.config import ModelConfig
+    from repro.models.decode import DecodeSpec
+    from repro.models.transformer import Model
+    from repro.serve.engine import ServeEngine
+
+    mesh = make_mesh((1, 1), ("data", "model"), devices=[topo.devices[0]])
+    cfg = ModelConfig(name="pool", arch_type="dense", n_layers=2, d_model=256,
+                      vocab_size=512, n_heads=4, n_kv_heads=4, head_dim=64, d_ff=512)
+    model = Model(cfg, MeshSpec(axes=("data", "model"), shape=(1, 1)),
+                  QSDPConfig(min_quant_size=256, bucket_size=128))
+    spec = DecodeSpec(cache_len=256, batch_global=4, batch_sharded=False,
+                      kv_block_size=16, kv_pool_blocks=64)
+    eng = ServeEngine(model, mesh, spec)
+
+    def on_chip(s, p):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=NamedSharding(mesh, p))
+
+    params = {n: on_chip(s, eng._pspecs[n]) for n, s in
+              jax.eval_shape(model.init_params, jax.random.PRNGKey(0)).items()}
+    structs, pspecs = eng.dm.cache_struct()
+    cache = {k: on_chip(s, pspecs[k]) for k, s in structs.items()}
+    lanes = on_chip(jax.ShapeDtypeStruct((4,), jnp.int32), P())
+    bt = on_chip(jax.ShapeDtypeStruct((4, spec.blocks_per_slot), jnp.int32), P())
+    key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32), P())
+    if program == "decode":
+        text = _compiled_text(eng.decode_step(), params, cache, lanes, lanes, bt, key)
+    else:
+        tokens = on_chip(jax.ShapeDtypeStruct((4, 16), jnp.int32), P())
+        text = _compiled_text(eng.prefill_chunk_step(16), params, cache, tokens,
+                              lanes, lanes, bt, key)
+    pool = "bf16[" + ",".join(map(str, structs["k"].shape)) + "]"
+    copies = [line for line in text.split("\n")
+              if " copy(" in line and line.split("=", 1)[1].lstrip().startswith(pool)]
+    assert copies == []

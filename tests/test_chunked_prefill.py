@@ -19,7 +19,10 @@ form, exactly as any chunked-prefill serving system must.)  Plus the
 bounded-retrace guarantee (at most n_buckets compiled prefill shapes for
 arbitrarily many distinct prompt lengths) and the dead-lane contract (a
 retired or never-filled lane's cache bytes are frozen — the pos = -1
-sentinel masks its ring write).
+sentinel masks its ring write).  The scheduler's chunk and decode steps
+take its weights encoded once for its gather key, while the solo reference
+re-encodes them every step from the f32 params, so each solo comparison
+also pins the resident path to the per-step gather.
 
 Engines and schedulers are cached at module scope (compiles dominate);
 reusing one scheduler across tests is deliberate — chunked admission never
@@ -173,11 +176,14 @@ def test_chunked_greedy_matches_solo(family, chunk, seed):
     generation lengths, admitted mid-decode through the chunked scheduler:
     every greedy request's tokens match its solo batch-of-1 run (same chunk
     decomposition) token-for-token, for chunk sizes 1 (token-at-a-time), 7
-    (multi-chunk with ragged tails), and 64 (single chunk > every prompt)."""
+    (multi-chunk with ragged tails), and 64 (single chunk > every prompt).
+    This compares the resident path (weights encoded once by the scheduler)
+    with the per-step gather of ``generate(fold_step_keys=False)``."""
     rng = np.random.default_rng(seed)
     sched = scheduler(family, 2, chunk)
     reqs = make_requests(rng, int(rng.integers(3, 6)))
     outs = run_scheduler(sched, reqs)
+    assert sched.stats()["weight_encodes"] == 1
     for r, got in zip(reqs, outs):
         ref = solo_tokens(family, r.prompt, r.max_new_tokens, chunk)
         np.testing.assert_array_equal(got, ref,
@@ -450,11 +456,14 @@ def paged_scheduler(slots=3, pool_blocks=0, share=True) -> ContinuousScheduler:
 def test_paged_interleaved_matches_solo(seed):
     """Random requests through the paged scheduler: every greedy stream
     matches the solo batch-of-1 run (same chunk decomposition) bit-for-bit,
-    wherever the allocator happened to place each block."""
+    wherever the allocator happened to place each block.  This compares the
+    resident path (weights encoded once by the scheduler) with the per-step
+    gather of ``generate(fold_step_keys=False)``."""
     rng = np.random.default_rng(seed)
     sched = paged_scheduler()
     reqs = make_requests(rng, int(rng.integers(3, 6)))
     outs = run_scheduler(sched, reqs)
+    assert sched.stats()["weight_encodes"] == 1
     for r, got in zip(reqs, outs):
         np.testing.assert_array_equal(
             got, solo_tokens("dense", r.prompt, r.max_new_tokens, PAGED_BS),
@@ -464,7 +473,9 @@ def test_paged_interleaved_matches_solo(seed):
 
 def test_paged_sampled_requests_match_solo():
     """Sampled requests under the paged pool reproduce their solo sampled
-    runs — block indirection must not perturb the per-request keying."""
+    runs — block indirection must not perturb the per-request keying.  This
+    compares the resident path with the per-step gather of
+    ``generate(fold_step_keys=False)``."""
     sched = paged_scheduler()
     rng = np.random.default_rng(19)
     reqs = [Request(rid=f"c{next(_RID)}",

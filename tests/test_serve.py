@@ -32,7 +32,8 @@ def test_cache_struct_shapes():
     m = _model()
     dm = DecodeModel(m, DecodeSpec(cache_len=32, batch_global=4, batch_sharded=True))
     structs, specs = dm.cache_struct()
-    assert structs["k"].shape == (2, 4, 32, 2, 16)
+    # (L, B, S, n_kv * hd): heads and head_dim share the last axis
+    assert structs["k"].shape == (2, 4, 32, 2 * 16)
     assert structs["k"].dtype == jnp.bfloat16
     assert set(structs) == set(specs) == {"k", "v"}
 

@@ -6,6 +6,9 @@ are BIT-IDENTICAL whether it runs alone in a batch-of-1 engine
 (``ServeEngine.generate(..., fold_step_keys=False)``) or interleaved with
 arbitrary other requests under the scheduler — random arrival orders,
 prompt/generation lengths, and slot counts, on the dense and moe families.
+The scheduler serves its weights encoded once for its gather key, and the
+solo engine re-encodes them every step from the f32 params, so each solo
+comparison also pins the resident path to the per-step gather.
 Plus: cache hygiene on slot reuse (no stale KV; ring wrap composes with
 reuse), and per-request sampling that is reproducible across runs and
 batch compositions and reduces to the greedy path bit-exactly at
@@ -130,11 +133,14 @@ def run_scheduler(sched, reqs):
 def test_interleaved_greedy_matches_solo(family, seed, slots):
     """Random arrival orders / prompt lengths / generation lengths / slot
     counts: every greedy request's tokens match its solo batch-of-1 run
-    token-for-token."""
+    token-for-token.  This compares the resident path (weights encoded once
+    by the scheduler) with the per-step gather of
+    ``generate(fold_step_keys=False)``."""
     rng = np.random.default_rng(seed)
     sched = scheduler(family, slots)
     reqs = make_requests(rng, int(rng.integers(3, 6)))
     outs = run_scheduler(sched, reqs)
+    assert sched.stats()["weight_encodes"] == 1
     for r, got in zip(reqs, outs):
         ref = solo_tokens(family, r.prompt, r.max_new_tokens)[: r.max_new_tokens]
         np.testing.assert_array_equal(got, ref, err_msg=f"{family} {r.rid}")
@@ -168,7 +174,8 @@ def test_slot_reuse_no_stale_kv(family):
     """More requests than slots forces freed-slot reuse mid-decode; every
     reused slot's request must match the fresh batch-of-1 engine, and a
     second pass over the same prompts (pool now dirty with the first pass's
-    KV) must reproduce it."""
+    KV) must reproduce it.  This compares the resident path with the
+    per-step gather of ``generate(fold_step_keys=False)``."""
     rng = np.random.default_rng(5)
     sched = scheduler(family, 2)
     reqs = make_requests(rng, 5, max_gen=4)
@@ -186,7 +193,8 @@ def test_slot_reuse_no_stale_kv(family):
 def test_ring_wrap_composes_with_slot_reuse():
     """Sliding-window model: generation long enough to wrap the KV ring,
     through slots that are freed and reused — wrap + reuse must still match
-    the solo run (which wraps the same ring)."""
+    the solo run (which wraps the same ring).  This compares the resident
+    path with the per-step gather of ``generate(fold_step_keys=False)``."""
     cfg = ModelConfig(name="wrap", arch_type="dense", n_layers=2, d_model=64,
                       vocab_size=VOCAB, n_heads=4, n_kv_heads=2, head_dim=16,
                       d_ff=128, sliding_window=0, long_context="sliding_window",

@@ -8,7 +8,10 @@ Load-bearing invariants:
   (``ServeEngine.generate(..., fold_step_keys=False)``) and sampled
   streams match the non-speculative scheduler, on the ring AND paged KV
   paths.  Speculation is a pure launch-count optimization; it may never
-  change a token.
+  change a token.  The scheduler's verify and draft steps take its weights
+  encoded once for its gather key, and the solo reference re-encodes them
+  every step, so the solo comparisons also pin the resident path to the
+  per-step gather.
 * The 2/3/4-bit rowquant re-quantization of the serving weights agrees
   with the serving-precision greedy argmax often enough to be a useful
   draft: acceptance per verify launch stays above a fixed per-bit-width
@@ -184,6 +187,9 @@ def test_decode_spec_speculative_property():
 
 @pytest.mark.parametrize("bits,depth", [(4, 4), (2, 3)])
 def test_greedy_speculative_matches_solo_ring(bits, depth):
+    """Greedy speculative streams on the ring equal the solo run: this
+    compares the resident path (weights encoded once by the scheduler) with
+    the per-step gather of ``generate(fold_step_keys=False)``."""
     rng = np.random.default_rng(10 * bits + depth)
     reqs = make_requests(rng, 5, f"g{bits}")
     done, _ = run_sched(scheduler(bits, depth), reqs)
@@ -191,9 +197,12 @@ def test_greedy_speculative_matches_solo_ring(bits, depth):
         ref = solo_tokens(r.prompt, r.max_new_tokens)
         assert np.array_equal(done[r.rid].tokens, ref), \
             (r.rid, done[r.rid].tokens.tolist(), ref.tolist())
+    assert scheduler(bits, depth).stats()["weight_encodes"] == 1
 
 
 def test_greedy_speculative_matches_solo_paged():
+    """The same on the paged pool: the resident path against the per-step
+    gather of ``generate(fold_step_keys=False)``."""
     rng = np.random.default_rng(3)
     reqs = make_requests(rng, 5, "p")
     done, delta = run_sched(scheduler(4, 4, paged=True), reqs)
